@@ -5,7 +5,7 @@ SpMV backends from a closed-form flops/bytes model and uses probes only to
 calibrate constants. This suite keeps the model honest against hardware
 truth on four calibration shapes spanning the planner's envelope (small /
 medium / wide-block / large), and pins the Pallas batch-grid kernel's
-bit-parity contract alongside the numbers:
+agreement with the XLA path alongside the numbers:
 
   shapes      per shape: measured probe ranking (``autotune
               .probe_backends``) vs uncalibrated analytic ranking
@@ -21,7 +21,9 @@ bit-parity contract alongside the numbers:
               machine-readable ``repro.cost/v1`` ranking report.
   parity      batched Pallas kernel (interpret mode on CPU) vs the
               ``bsr_ml`` batched path on a capacity-padded batch with
-              streaming holes. GATE: bitwise equal, not approx.
+              streaming holes. GATE: equal to float32 rounding (1e-5;
+              the kernel sums the ELL slots in chunks, bsr_ml in one
+              contraction, so the order of the adds differs).
 
   PYTHONPATH=src:. python benchmarks/run.py --only bench_kernels
 """
@@ -115,11 +117,12 @@ def run(emit) -> None:
     got = np.asarray(jax.block_until_ready(
         api._batch_apply_kernel(pb.spec, pb.data, xs, "pallas", "apply")))
     t_pallas = time.perf_counter() - t0
-    bit_equal = bool(np.array_equal(got, want))
+    err = float(np.abs(got - want).max())
+    close = bool(np.allclose(got, want, rtol=1e-5, atol=1e-5))
     emit(f"bench_kernels/parity_batched_B4,{t_pallas * 1e6:.0f},"
-         f"bit_equal={int(bit_equal)};holes=17")
-    assert bit_equal, (
-        "batched pallas backend is not bit-identical to bsr_ml on a "
+         f"max_abs_err={err:.2e};holes=17")
+    assert close, (
+        f"batched pallas backend differs from bsr_ml by {err:.2e} on a "
         "capacity-padded batch with streaming holes")
 
 

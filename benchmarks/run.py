@@ -15,7 +15,7 @@ diff them — the bench trajectory convention is ``BENCH_plan.json``.
   bench_batch      beyond-paper  (PlanBatch vmapped matvec vs plan loop)
   bench_serve      beyond-paper  (decode service vs per-call Morton sort)
   bench_kernels    beyond-paper  (analytic cost model vs probe ranking,
-                                  batched Pallas bit-parity)
+                                  batched Pallas parity)
   bench_solvers    beyond-paper  (batched block-Jacobi CG vs plain CG
                                   vs per-plan eager solve loop)
 

@@ -108,7 +108,8 @@ from repro.core.blocksparse import (BSR, append_rows, build_bsr, patch_bsr,
 from repro.core.embedding import apply_pca_map, embed, pca_map
 from repro.core.hierarchy import Tree, build_tree
 from repro.core.ordering import ORDERINGS  # noqa: F401  (re-export)
-from repro.core.registry import (backend_names, get_backend,  # noqa: F401
+from repro.core.registry import (NotApplicable,  # noqa: F401
+                                 backend_names, get_backend,
                                  get_batched_backend,
                                  get_preconditioner, preconditioner_names,
                                  register_backend,
@@ -559,7 +560,7 @@ class InteractionPlan:
     def coo(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Reordered COO ``(rows, cols, vals)`` (cluster index space)."""
         if self.host.coo is None:
-            raise ValueError("plan has no COO pattern (built from_bsr)")
+            raise NotApplicable("plan has no COO pattern (built from_bsr)")
         return self.host.coo
 
     def coo_device(self) -> Tuple[jax.Array, jax.Array, jax.Array]:

@@ -1,73 +1,46 @@
-"""Version-tolerant wrappers over JAX APIs that moved between releases.
+"""The one home of JAX APIs this repo pins to the installed release (0.9).
 
-``shard_map`` has lived in three places/shapes:
+Call sites import ``shard_map``, ``make_mesh``, ``abstract_mesh``,
+``axis_size`` and ``is_batch_tracer`` from here, never from ``jax``
+directly (``tests/test_lint_imports.py``), so the next API move is one
+edit.
 
-  - ``jax.experimental.shard_map.shard_map`` with ``check_rep=``  (<= 0.4.x)
-  - ``jax.shard_map`` with ``check_rep=``                         (~0.5.x)
-  - ``jax.shard_map`` with ``check_vma=``                         (>= 0.6.x)
-
-All repro call sites import ``shard_map`` from here and pass ``check_vma=``;
-the wrapper renames the kwarg to whatever the installed JAX expects.
+Meshes: since JAX 0.7 ``jax.make_mesh`` defaults every axis to
+``AxisType.Explicit``, under which a gather or scatter on a sharded
+operand raises ``ShardingTypeError`` and one mesh axis may not appear
+twice in a ``PartitionSpec``. This repo's sharding is written for the
+compiler-propagated (``Auto``) semantics, so every mesh is made here with
+``AxisType.Auto`` on each axis.
 """
 from __future__ import annotations
 
-import inspect
+import jax
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-try:  # newer JAX exports it at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` with the replication-check kwarg normalized."""
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        check = kwargs.pop("check_vma")
-        if "check_rep" in _PARAMS:
-            kwargs["check_rep"] = check
-    return _shard_map(f, **kwargs)
+def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
-try:
-    from jax.interpreters.batching import BatchTracer as _BatchTracer
-except ImportError:  # pragma: no cover - depends on installed JAX
-    _BatchTracer = None
+def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
+    """Device-free mesh of the given shape, every axis ``AxisType.Auto``."""
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def is_batch_tracer(x) -> bool:
     """True when ``x`` is a ``jax.vmap`` batching tracer.
 
-    Used by the plan API to turn the opaque shape/hash errors a vmapped
+    The plan API uses it to turn the opaque shape/hash errors a vmapped
     ``InteractionPlan`` produces into a descriptive ``TypeError`` pointing
-    at ``PlanBatch``. The tracer class has lived in
-    ``jax.interpreters.batching`` for every supported release, but it is
-    internal — the import is fenced (at module load, off the hot path) so
-    an upstream move degrades to "no early detection", not ImportError.
+    at ``PlanBatch``. The tracer class is internal (it left
+    ``jax.interpreters.batching``), so it is recognised by name.
     """
-    return _BatchTracer is not None and isinstance(x, _BatchTracer)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (newer JAX) with the classic constant-folding
-    ``psum(1, axis)`` fallback (static under shard_map/pmap on 0.4.x)."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across ctor-signature changes.
-
-    Newer JAX takes ``(axis_sizes, axis_names)``; 0.4.x takes a single
-    tuple of ``(name, size)`` pairs.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return isinstance(x, jax.core.Tracer) \
+        and type(x).__name__ == "BatchTracer"

@@ -37,8 +37,8 @@ import numpy as np
 from repro.configs.base import ClusterKVConfig
 from repro.core import clusterkv as ckv
 from repro.core import costmodel
-from repro.core.registry import backend_names, get_backend, \
-    get_batched_backend
+from repro.core.registry import NotApplicable, backend_names, \
+    get_backend, get_batched_backend
 
 
 # ---------------------------------------------------------------------------
@@ -54,9 +54,10 @@ from repro.core.registry import backend_names, get_backend, \
 _TUNE_MEMO: Dict[tuple, dict] = {}
 
 # calibration constants: backend name (or "batch:<name>") -> measured /
-# modeled seconds ratio from ONE probe. inf marks a backend that failed or
-# was skipped (interpret-mode pallas, broken probe) — excluded from
-# rankings. This is the only place the stopwatch touches the decision.
+# modeled seconds ratio from ONE probe. inf marks a backend that was
+# skipped (interpret-mode pallas on the CPU, a NotApplicable refusal, a
+# wrong answer) — excluded from rankings. This is the only place the
+# stopwatch touches the decision.
 _CALIB: Dict[str, float] = {}
 
 
@@ -86,21 +87,20 @@ def probe_backends(plan, x: Optional[jax.Array] = None,
                    include_interpret: bool = False) -> Dict[str, float]:
     """Median wall time (s) per registered backend on the plan's shapes.
 
-    Backends that raise (missing COO, mesh indivisibility, ...) or disagree
-    with the flat block path by more than ``atol`` max-abs are skipped —
-    a fast-but-wrong backend must never win the autotune. Interpret-mode
-    Pallas backends are skipped by default (they pay a compile + timed
-    interpreter runs and can never win on CPU); pass
-    ``include_interpret=True`` to time them anyway (tests).
+    Backends that refuse with :class:`NotApplicable` (missing COO, 1-D
+    only) or disagree with the flat block path by more than ``atol``
+    max-abs are skipped — a fast-but-wrong backend must never win the
+    autotune. Any other exception is a fault and propagates: a kernel
+    that fails to compile on the chip must not be quietly replaced by an
+    XLA path. Interpret-mode Pallas backends (the CPU) are skipped by
+    default (a compile + timed interpreter runs that can never win);
+    pass ``include_interpret=True`` to time them anyway (tests).
     """
     if x is None:
         x = jnp.asarray(
             np.random.default_rng(0).standard_normal(plan.n), jnp.float32)
     names = tuple(backends) if backends is not None else backend_names()
-    try:
-        ref = np.asarray(jax.block_until_ready(get_backend("bsr")(plan, x)))
-    except Exception:
-        ref = None
+    ref = np.asarray(jax.block_until_ready(get_backend("bsr")(plan, x)))
     times: Dict[str, float] = {}
     for name in names:
         fn = get_backend(name)
@@ -108,7 +108,7 @@ def probe_backends(plan, x: Optional[jax.Array] = None,
             continue
         try:
             y = np.asarray(jax.block_until_ready(fn(plan, x)))
-            if ref is not None and np.abs(y - ref).max() > atol:
+            if np.abs(y - ref).max() > atol:
                 continue
             for _ in range(warmup):
                 jax.block_until_ready(fn(plan, x))
@@ -118,7 +118,7 @@ def probe_backends(plan, x: Optional[jax.Array] = None,
                 jax.block_until_ready(fn(plan, x))
                 ts.append(time.perf_counter() - t0)
             times[name] = float(np.median(ts))
-        except Exception:
+        except NotApplicable:
             continue
     return times
 
@@ -127,8 +127,8 @@ def _calibrate(names: Iterable[str], feat, plan, x, *,
                interpret: bool) -> None:
     """Probe every backend in ``names`` that has no calibration constant
     yet and store measured/modeled ratios in ``_CALIB``. A backend whose
-    probe fails, disagrees, or is interpret-mode Pallas calibrates to inf
-    (excluded from rankings until ``clear_calibration``)."""
+    probe refuses, disagrees, or is interpret-mode Pallas calibrates to
+    inf (excluded from rankings until ``clear_calibration``)."""
     missing = [n for n in names if n not in _CALIB]
     if not missing:
         return
@@ -151,7 +151,7 @@ def tune_backend(plan, x: Optional[jax.Array] = None,
 
     Returns ``(name, calibrated predicted seconds per backend)``; the
     winner is the argmin of the returned dict. Falls back to ``"bsr"``
-    when nothing is rankable (tracer plans, every probe failed).
+    when nothing is rankable (tracer plans, every probe refused).
 
     Probes are demoted to calibration: the first time a backend is seen
     it is timed once and the measured/modeled ratio memoized globally
@@ -199,8 +199,8 @@ def tune_backend(plan, x: Optional[jax.Array] = None,
     local = tuple(n for n in names if n != "dist")
     _calibrate(local, feat, plan, x, interpret=interp)
     if ndev >= 2 and "dist" in names and "dist" not in _CALIB:
-        # dist needs a real mesh to calibrate; a failed probe marks it
-        # non-viable here (e.g. indivisible shard counts)
+        # dist needs a real mesh to calibrate; a refusal (e.g. (n, f)
+        # charges) marks it non-viable here
         _calibrate(("dist",), feat, plan, x, interpret=False)
     report = costmodel.rank_backends(
         feat, local, calibration=_CALIB, interpret=interp, n_dev=ndev)
@@ -237,8 +237,9 @@ def tune_batch_backend(batch, x: Optional[jax.Array] = None,
     the *batched* kernel itself (``api._batch_apply_kernel``) — the
     single-plan calibration does not transfer (batching changes the
     gather shapes and dispatch count), so batch backends calibrate under
-    ``"batch:<name>"`` keys. Backends that fail to batch or disagree with
-    the batched ``bsr`` path calibrate to inf. The decision is memoized
+    ``"batch:<name>"`` keys. Backends that refuse (:class:`NotApplicable`)
+    or disagree with the batched ``bsr`` path calibrate to inf; any other
+    exception propagates. The decision is memoized
     on ``(batch shape_key, B, charge ndim, backend set)`` with the full
     ranking report: spec-identical batches — every construction in a
     serving loop — tune once.
@@ -265,11 +266,8 @@ def tune_batch_backend(batch, x: Optional[jax.Array] = None,
         if x is None:
             x = jnp.asarray(np.random.default_rng(0).standard_normal(
                 (batch.batch, batch.capacity)), jnp.float32)
-        try:
-            ref = np.asarray(jax.block_until_ready(api._batch_apply_kernel(
-                batch.spec, batch.data, x, "bsr", "apply")))
-        except Exception:
-            ref = None
+        ref = np.asarray(jax.block_until_ready(api._batch_apply_kernel(
+            batch.spec, batch.data, x, "bsr", "apply")))
         for name in missing:
             ckey = "batch:" + name
             bfn = get_batched_backend(name)
@@ -280,7 +278,7 @@ def tune_batch_backend(batch, x: Optional[jax.Array] = None,
                 y = np.asarray(jax.block_until_ready(
                     api._batch_apply_kernel(
                         batch.spec, batch.data, x, name, "apply")))
-                if ref is not None and np.abs(y - ref).max() > atol:
+                if np.abs(y - ref).max() > atol:
                     _CALIB[ckey] = float("inf")
                     continue
                 for _ in range(warmup):
@@ -297,7 +295,7 @@ def tune_batch_backend(batch, x: Optional[jax.Array] = None,
                     feat, name, interpret=interp)["seconds"]
                 _CALIB[ckey] = meas / model_s if model_s > 0 \
                     else float("inf")
-            except Exception:
+            except NotApplicable:
                 _CALIB[ckey] = float("inf")
     cal = {n: _CALIB.get("batch:" + n, 1.0) for n in names}
     report = costmodel.rank_backends(feat, names, calibration=cal,

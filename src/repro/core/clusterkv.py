@@ -55,32 +55,9 @@ def masked_softmax(logit: jax.Array, mask: jax.Array) -> jax.Array:
 
 def decode_logits(qh: jax.Array, ksel: jax.Array) -> jax.Array:
     """Scaled q·k logits for one (batch, kv-head) slice: qh (g,dh) float32,
-    ksel (c,dh) float32 -> (g,c).
-
-    The form is conditioned on the STATIC group size because the decode
-    bitwise gate compares a per-slice kernel against the vmapped XLA
-    reference: an M=1 dot is strength-reduced by XLA:CPU into a fused
-    multiply+reduce whose rounding depends on the surrounding fusion
-    context, so no per-slice form can reproduce it stably. Padding the
-    single query row to M=2 keeps the contraction a real materialized
-    GEMM — bit-stable between the per-slice and vmapped lowerings — at
-    the cost of one duplicated row of a tiny matvec. g >= 2 is already
-    a real matmul and hits the MXU unchanged."""
+    ksel (c,dh) float32 -> (g,c)."""
     scale = jnp.sqrt(jnp.asarray(qh.shape[-1], jnp.float32))
-    if qh.shape[0] == 1:
-        q2 = jnp.concatenate([qh, qh], axis=0)
-        return (q2 @ ksel.T)[:1] / scale
     return qh @ ksel.T / scale
-
-
-def decode_combine(w: jax.Array, vsel: jax.Array) -> jax.Array:
-    """Weighted value combine w (g,c) @ vsel (c,dv) float32 -> (g,dv),
-    with the same static g == 1 row-padding as :func:`decode_logits`
-    (the output dot is M=1 there too)."""
-    if w.shape[0] == 1:
-        w2 = jnp.concatenate([w, w], axis=0)
-        return (w2 @ vsel)[:1]
-    return w @ vsel
 
 
 # ---------------------------------------------------------------------------
@@ -286,8 +263,34 @@ def decode_select(q: jax.Array, centroids: jax.Array, n_sel: int) -> jax.Array:
     # multiply+reduce, not einsum: the grouped query is a single row per
     # kv head, and an M=1 contraction is strength-reduced shape-dependently
     # by XLA:CPU — the elementwise form scores identically per-slice and
-    # batched, which the fused decode kernel's bitwise gate relies on
+    # batched
     scores = jnp.sum(qg[:, :, None, :] * centroids, -1)
+    _, idx = jax.lax.top_k(scores, n_sel)
+    return idx.astype(jnp.int32)
+
+
+def plan_decode_select(q: jax.Array, ps: jax.Array, cent: jax.Array,
+                       qpos: jax.Array, n_sel: int, bk: int,
+                       window: int) -> jax.Array:
+    """Tile selection over a PLAN-ordered cache (the decode service).
+
+    q (B,Hq,dh); ps (B,Hkv,S) time position per plan slot (``INT32_MAX``
+    marks a capacity hole); cent (B,Hkv,S/bk,dh); qpos (B,). Tiles with no
+    live position (all > qpos) never win, and tiles holding a position
+    within ``window`` of qpos (the causal frontier) are boosted ahead of
+    the rest. Returns idx (B,Hkv,n_sel) int32."""
+    b, hq, dh = q.shape
+    hkv, s = ps.shape[1], ps.shape[2]
+    pt = ps.reshape(b, hkv, s // bk, bk)
+    qp = qpos.astype(jnp.int32)
+    live = pt <= qp[:, None, None, None]              # causal AND not-a-hole
+    tile_has = live.any(-1)                           # (B,Hkv,nkb)
+    qg = q.reshape(b, hkv, hq // hkv, dh).mean(axis=2).astype(jnp.float32)
+    scores = jnp.sum(qg[:, :, None, :] * cent.astype(jnp.float32), -1)
+    scores = jnp.where(tile_has, scores, NEG_INF)
+    recent = jnp.where(live, pt, -1).max(-1)
+    near = recent >= (qp[:, None, None] - window)
+    scores = jnp.where(near & tile_has, scores + 1e4, scores)
     _, idx = jax.lax.top_k(scores, n_sel)
     return idx.astype(jnp.int32)
 
@@ -321,7 +324,7 @@ def decode_attend(q: jax.Array, k: jax.Array, v: jax.Array,
         # guarded: an early-position decode can select only holes/future
         # tiles, and an unguarded softmax would weight them uniformly
         w = masked_softmax(logit, psel[None, :] <= qpos)
-        return decode_combine(w, vsel.astype(jnp.float32)).astype(q.dtype)
+        return (w @ vsel.astype(jnp.float32)).astype(q.dtype)
 
     out = jax.vmap(jax.vmap(per_bh))(
         q.reshape(b, hkv, g, dh), kb, vb, pb, idx)

@@ -85,15 +85,43 @@ class HardwareConfig:
 
 _HARDWARE: Optional[HardwareConfig] = None
 
+# knobs per ``jax.Device.device_kind``. Peaks: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI
+# per chip); VMEM is the default scoped-VMEM limit a kernel may use.
+DEVICE_KNOBS: Dict[str, HardwareConfig] = {
+    "TPU v5 lite": HardwareConfig(),
+}
+
+
+def target_hardware() -> HardwareConfig:
+    """The knobs of the chip being planned for, without asking JAX for a
+    device: the JSON file named by ``REPRO_HW_CONFIG``, else TPU v5e.
+    Dry-run planning and the CPU (which rehearses for the v5e) use it."""
+    path = os.environ.get("REPRO_HW_CONFIG")
+    return HardwareConfig.from_json(path) if path else HardwareConfig()
+
 
 def get_hardware() -> HardwareConfig:
-    """The active hardware config: ``set_hardware``'s, else the JSON file
-    named by ``REPRO_HW_CONFIG``, else the built-in TPU v5e knobs."""
+    """The active hardware config: ``set_hardware``'s, else
+    :func:`target_hardware` on the CPU (the v5e is its rehearsal target)
+    or when ``REPRO_HW_CONFIG`` is set, else the knobs of the attached
+    accelerator's ``device_kind``. An accelerator with no knobs is an
+    error, never a default."""
     global _HARDWARE
     if _HARDWARE is None:
-        path = os.environ.get("REPRO_HW_CONFIG")
-        _HARDWARE = (HardwareConfig.from_json(path) if path
-                     else HardwareConfig())
+        import jax
+
+        dev = jax.devices()[0]
+        if dev.platform == "cpu" or os.environ.get("REPRO_HW_CONFIG"):
+            _HARDWARE = target_hardware()
+        elif dev.device_kind in DEVICE_KNOBS:
+            _HARDWARE = DEVICE_KNOBS[dev.device_kind]
+        else:
+            raise ValueError(
+                f"no hardware knobs for device kind {dev.device_kind!r} "
+                f"({dev.platform}); known: {sorted(DEVICE_KNOBS)}. Add its "
+                "published peaks to costmodel.DEVICE_KNOBS or point "
+                "REPRO_HW_CONFIG at a knob file.")
     return _HARDWARE
 
 
@@ -503,6 +531,12 @@ def exchange_cost(transfer_blocks: "int | None", bs: int,
 # ---------------------------------------------------------------------------
 
 
+def _vmem_bytes(rows: int, cols: int) -> float:
+    """VMEM bytes of one float32 ``(rows, cols)`` tile, padded to the
+    (8, 128) register tiling."""
+    return -(-rows // 8) * 8 * -(-cols // 128) * 128 * _ELEM
+
+
 def choose_tiles(shape_key: Tuple[int, ...], f: int = 1,
                  hw: Optional[HardwareConfig] = None
                  ) -> Tuple[int, int, int]:
@@ -510,27 +544,36 @@ def choose_tiles(shape_key: Tuple[int, ...], f: int = 1,
 
     ``rbs`` row blocks ride one grid step (amortizing grid overhead),
     ``chunk`` ELL slots are contracted per step, and charges are tiled to
-    ``fc`` feature columns. ``chunk`` stays at the full ELL width: a
-    split slot reduction changes the floating-point summation order and
-    breaks the bit-parity gate against the XLA paths (the CPU-container
-    acceptance); memory pressure is instead relieved by shrinking ``fc``
-    then ``rbs``. Resident VMEM per step is the vals block
-    ``rbs*chunk*bs^2``, the charge block ``n_cb*bs*fc`` and the output
-    block ``rbs*bs*fc``.
+    ``fc`` feature columns, a multiple of 128 lanes. VMEM per step is
+    counted with the (8, 128) padding: the double-buffered value tiles
+    (lane-dense ``(bs*bs/W, W)``, ``W = max(bs*Q, 128)``), the gathered
+    ``(bs, fc)`` charge segments and the double-buffered ``(bs, fc)``
+    output tiles. Half the VMEM knob is the budget (headroom for the
+    compiler's own scratch). ``chunk`` splits the ELL width as evenly as
+    the budget allows, so VMEM does not grow with it.
     """
     capacity, bs, sb, n_rb, n_cb, max_nbr = shape_key
     hw = hw or get_hardware()
-    budget = hw.vmem_bytes / 2          # leave headroom for double-buffering
-    chunk = max(int(max_nbr or 1), 1)
-    fc = max(int(f), 1)
-    while fc > 1 and n_cb * bs * fc * _ELEM > budget / 2:
-        fc = -(-fc // 2)
+    budget = hw.vmem_bytes / 2
+    nbr = max(int(max_nbr or 1), 1)
+    q = max(1, 128 // bs)
+    tile_b = 2 * _vmem_bytes(max(bs // q, 1), bs * q)
+    fc = -(-max(int(f), 1) // 128) * 128
+
+    def per_slot(fc_: int) -> float:
+        return tile_b + _vmem_bytes(bs, fc_)
+
+    def out_b(fc_: int) -> float:
+        return 2 * _vmem_bytes(bs, fc_)
+
+    while fc > 128 and per_slot(fc) + out_b(fc) > budget:
+        fc = max(128, (fc // 2) // 128 * 128)
+    cap = max(int((budget - out_b(fc)) // per_slot(fc)), 1)
+    n_ch = -(-nbr // cap)
+    chunk = -(-nbr // n_ch)
 
     def fits(r: int) -> bool:
-        vals_b = r * chunk * bs * bs * _ELEM
-        y_b = r * bs * fc * _ELEM
-        x_b = n_cb * bs * fc * _ELEM
-        return vals_b + y_b + x_b <= budget
+        return r * (chunk * per_slot(fc) + out_b(fc)) <= budget
 
     rbs = 1
     while rbs * 2 <= min(max(n_rb, 1), 8) and fits(rbs * 2):

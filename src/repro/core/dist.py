@@ -17,7 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.compat import shard_map
 
 from repro.core.blocksparse import BSR
-from repro.core.registry import register_backend
+from repro.core.registry import NotApplicable, register_backend
 
 
 def spmv_sharded(bsr: BSR, x: jax.Array, mesh: Mesh, axis: str = "data"
@@ -31,7 +31,7 @@ def spmv_sharded(bsr: BSR, x: jax.Array, mesh: Mesh, axis: str = "data"
     shape (n,) — reject (n, f) loudly rather than scrambling it.
     """
     if x.ndim != 1:
-        raise ValueError(f"spmv_sharded supports 1-D charges only, "
+        raise NotApplicable(f"spmv_sharded supports 1-D charges only, "
                          f"got x.shape={x.shape}")
     n_rb = bsr.vals.shape[0]
     size = mesh.shape[axis]

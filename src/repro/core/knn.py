@@ -37,8 +37,13 @@ def knn_graph(targets: jax.Array, sources: jax.Array, k: int,
     def body(_, tb):
         qb, base = tb
         q32 = qb.astype(jnp.float32)
+        # HIGHEST: the TPU's default f32 matmul rounds its inputs to
+        # bfloat16 (~3 significant digits), which reorders near neighbors;
+        # the exact-kNN contract needs float32 products
+        cross = jnp.dot(q32, sources.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST)
         d2 = (jnp.sum(q32**2, axis=1)[:, None] + s_norm[None, :]
-              - 2.0 * q32 @ sources.astype(jnp.float32).T)
+              - 2.0 * cross)
         if exclude_self:
             rows = base + jnp.arange(qb.shape[0])
             d2 = d2 + (rows[:, None] == jnp.arange(n)[None, :]) * jnp.inf

@@ -25,6 +25,14 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Tuple
 
+
+class NotApplicable(ValueError):
+    """A backend's typed refusal: it does not apply to this plan or charge
+    (a plan with no COO for ``csr``, an (n, f) charge for the 1-D-only
+    ``dist`` path). Autotune probes skip a backend that raises this; any
+    other exception is a fault and propagates."""
+
+
 _BACKENDS: Dict[str, Callable] = {}
 _BATCHED: Dict[str, Callable] = {}
 _DECODE: Dict[str, Callable] = {}

@@ -48,9 +48,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.compat import make_mesh, shard_map
 from repro.core import costmodel
 from repro.core.blocksparse import BSR
+from repro.core.registry import NotApplicable
 
 __all__ = ["ShardSpec", "ShardedPlan", "analyze_shards", "shard",
            "default_mesh"]
@@ -60,7 +61,7 @@ __all__ = ["ShardSpec", "ShardedPlan", "analyze_shards", "shard",
 def default_mesh(axis: str = "data") -> Mesh:
     """1-axis mesh over every local device (shared by `shard` and the
     `dist` registry backend, so their memoized shards agree)."""
-    return jax.make_mesh((jax.device_count(),), (axis,))
+    return make_mesh((jax.device_count(),), (axis,))
 
 
 @dataclass(frozen=True)
@@ -344,7 +345,7 @@ class ShardedPlan:
         """``y = A' x`` in cluster order via the sharded halo path."""
         x = jnp.asarray(x)
         if x.ndim != 1:
-            raise ValueError(f"sharded plans take 1-D charges, got "
+            raise NotApplicable(f"sharded plans take 1-D charges, got "
                              f"shape {x.shape}")
         if self._fn is None:
             self._fn = jax.jit(self._local_matvec())

@@ -1,39 +1,30 @@
-"""Pallas TPU kernel: fused single-token cluster decode attention.
+"""Pallas TPU kernel: fused cluster-tile gather + single-token attend.
 
 The decode-side twin of ``bsr_spmv``'s batch-grid kernel. The unfused XLA
-path pays two dispatches per tick (``decode_select`` top-k, then
-``decode_attend``'s vmapped tile gather) and the gather materializes the
-selected k/v tiles back through HBM before the attend reads them again.
-This kernel runs the whole chain per (batch member, kv head) grid step:
+path gathers the selected k/v tiles (a vmapped take that writes them back
+to HBM) and then runs the attend over the copy. This kernel attends
+straight off the cache: grid ``(batch member, kv head, selected tile)``,
+where the tile index arrives by scalar prefetch, so the Pallas pipeline
+DMAs each selected ``(bk, d)`` tile from HBM into VMEM exactly once
+(double-buffered against the previous tile's compute) and nothing else of
+the cache moves. The softmax runs online across the tiles (running max,
+normalizer and weighted sum in VMEM scratch) and is guarded: a selection
+with no live position yields exact zeros, like
+``core.clusterkv.masked_softmax``.
 
-  centroid scoring -> top-c tile selection -> selected-tile DMA gather
-  -> masked-softmax attend
+Tile *selection* stays in XLA — ``core.clusterkv.decode_select`` (plain
+caches) or ``plan_decode_select`` (the decode service's plan-ordered
+caches: holes masked, local-window boost) — a top-k over ``S/bk``
+centroid scores, shared with the XLA backends, so both backends attend the
+same tiles. With ``has_self`` the current token's own k/v join the
+softmax as an always-visible extra column (the service lands each token in
+the plan one tick later).
 
-so each selected tile streams from HBM exactly once, straight into VMEM
-scratch (``pltpu.make_async_copy`` off the ``ANY``-space cache refs), and
-nothing else of the cache moves at all. Per-slot decode positions arrive
-via the ``PrefetchScalarGridSpec`` scalar-prefetch channel, the same
-pattern that feeds ``bsr_spmv`` its column indices.
-
-Two static contracts share the body:
-
-* plain mode (``plan_mode=False``) — bitwise-identical to the pure-JAX
-  ``core.clusterkv.decode_select`` + ``decode_attend`` pair (the
-  CPU-container acceptance gate, asserted in interpret mode): raw
-  centroid scores, ``lax.top_k`` tie semantics via iterative first-argmax,
-  one guarded softmax over the concatenated selection.
-* plan mode (``plan_mode=True``) — the decode service's
-  ``clusterkv_plan_decode`` contract over plan-ordered caches: hole tiles
-  (all positions > qpos) are masked out of selection, the local-window
-  recency boost keeps the causal frontier, and the current token's own
-  k/v ride an always-visible extra column (``has_self``).
-
-Bit-parity notes (same discipline as ``bsr_spmv``): the selection scores,
-gather order, and the single softmax over the concatenated ``c*bk`` axis
-mirror the reference op for op — an online softmax across tiles would
-reassociate the normalizer sum and break the bitwise gate. ``lax.top_k``
-orders descending with ties to the LOWEST index; n_sel rounds of
-min-index-of-max with mask-out replicate that exactly.
+Every block keeps its last two dims whole (k/v tiles ``(bk, d)``,
+positions ``(1, bk)``), which the chip's tiling rule accepts at any head
+width; a manual DMA slice of a 64-wide head is refused. Agreement with
+the XLA reference (one softmax over the concatenated selection) is a
+float32 tolerance, not bitwise.
 """
 from __future__ import annotations
 
@@ -45,142 +36,104 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-_BIG = 2 ** 31 - 1
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(qpos_ref, q_ref, cent_ref, ps_ref, k_ref, v_ref, kself_ref,
-            vself_ref, o_ref, k_scr, v_scr, k_sem, v_sem, *, n_sel, bk,
-            nkb, dh, dv, plan_mode, has_self, window):
+def _kernel(idx_ref, qpos_ref, q_ref, k_ref, v_ref, p_ref, kself_ref,
+            vself_ref, o_ref, m_scr, l_scr, acc_scr, *, n_sel, has_self):
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    j = pl.program_id(2)
     qp = qpos_ref[b]
-
-    # -- centroid scoring (mirrors decode_select / clusterkv_plan_decode) --
     qf = q_ref[0, 0].astype(jnp.float32)              # (g, dh)
-    qm = jnp.mean(qf, axis=0)                         # grouped query
-    cent = cent_ref[0, 0].astype(jnp.float32)         # (nkb, dh)
-    # multiply+reduce mirrors ckv.decode_select's batching-stable scoring
-    scores = jnp.sum(cent * qm[None, :], -1).reshape(1, nkb)
-    pt = ps_ref[0, 0].reshape(nkb, bk)                # int32 positions
-    if plan_mode:
-        live = pt <= qp                               # causal AND not-a-hole
-        tile_has = live.any(-1).reshape(1, nkb)
-        scores = jnp.where(tile_has, scores, NEG_INF)
-        recent = jnp.where(live, pt, -1).max(-1).reshape(1, nkb)
-        near = recent >= qp - window
-        scores = jnp.where(near & tile_has, scores + 1e4, scores)
+    scale = jnp.sqrt(jnp.asarray(qf.shape[-1], jnp.float32))
 
-    # -- top-c selection: n_sel rounds of first-argmax with mask-out ------
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, nkb), 1)
-    sel = []
-    cur = scores
-    for _ in range(n_sel):
-        t = jnp.min(jnp.where(cur == jnp.max(cur), iota, nkb))
-        sel.append(t)
-        cur = jnp.where(iota == t, -jnp.inf, cur)
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # -- DMA-gather the selected tiles HBM -> VMEM scratch, overlapped ----
-    for j, t in enumerate(sel):
-        pltpu.make_async_copy(k_ref.at[b, h, pl.ds(t * bk, bk), :],
-                              k_scr.at[pl.ds(j * bk, bk), :],
-                              k_sem.at[j]).start()
-        pltpu.make_async_copy(v_ref.at[b, h, pl.ds(t * bk, bk), :],
-                              v_scr.at[pl.ds(j * bk, bk), :],
-                              v_sem.at[j]).start()
-    for j, t in enumerate(sel):
-        pltpu.make_async_copy(k_ref.at[b, h, pl.ds(t * bk, bk), :],
-                              k_scr.at[pl.ds(j * bk, bk), :],
-                              k_sem.at[j]).wait()
-        pltpu.make_async_copy(v_ref.at[b, h, pl.ds(t * bk, bk), :],
-                              v_scr.at[pl.ds(j * bk, bk), :],
-                              v_sem.at[j]).wait()
-    ksel = k_scr[...]                                 # (n_sel*bk, dh)
-    vsel = v_scr[...]                                 # (n_sel*bk, dv)
-    psel = jnp.concatenate(
-        [jax.lax.dynamic_index_in_dim(pt, t, 0, keepdims=False)
-         for t in sel])
-    if plan_mode:
-        spos = qp if has_self else jnp.int32(_BIG)
-        ksel = jnp.concatenate([ksel, kself_ref[0, 0][None, :]], axis=0)
-        vsel = jnp.concatenate([vsel, vself_ref[0, 0][None, :]], axis=0)
-        psel = jnp.concatenate([psel, jnp.full((1,), spos, jnp.int32)])
+    def update(logit, mask, v):
+        """Fold one block of columns into the running softmax."""
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(jnp.where(mask, logit, NEG_INF),
+                                            axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        e = jnp.where(mask, jnp.exp(logit - m_new), 0.0)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=-1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            e, v, precision=_HIGHEST, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
-    # -- one guarded softmax over the whole selection (see _masked_softmax
-    # in core.clusterkv: bitwise jax.nn.softmax whenever a column is live,
-    # exact zeros when the selection is empty) ----------------------------
-    # einsum, not ``qf @ ksel.T``: the reference computes this matmul
-    # under vmap, whose batched dot_general contracts d without
-    # materializing the transpose, and the transposed per-slice form
-    # rounds differently on XLA:CPU. g == 1 pads the query row to M=2
-    # (mirroring ckv.decode_logits/decode_combine): an M=1 dot is
-    # strength-reduced by XLA:CPU with fusion-context-dependent rounding,
-    # while the padded GEMM is bit-stable per-slice vs vmapped.
-    kf = ksel.astype(jnp.float32)
-    vf = vsel.astype(jnp.float32)
-    scale = jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    g = qf.shape[0]
-    qpad = jnp.concatenate([qf, qf], axis=0) if g == 1 else qf
-    logit = (jnp.einsum("gd,cd->gc", qpad, kf) / scale)[:g]
-    mask = psel[None, :] <= qp
-    logit = jnp.where(mask, logit, NEG_INF)
-    m = jnp.max(logit, axis=-1, keepdims=True)
-    e = jnp.exp(logit - jax.lax.stop_gradient(m))
-    e = jnp.where(mask, e, 0.0)
-    w = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-    wpad = jnp.concatenate([w, w], axis=0) if g == 1 else w
-    o_ref[0, 0] = (wpad @ vf)[:g].astype(o_ref.dtype)
+    kf = k_ref[0, 0].astype(jnp.float32)              # (bk, dh)
+    logit = jax.lax.dot_general(
+        qf, kf, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32) / scale   # (g, bk)
+    update(logit, p_ref[0, 0, 0] <= qp, v_ref[0, 0].astype(jnp.float32))
+
+    @pl.when(j == n_sel - 1)
+    def _finish():
+        if has_self:
+            ks = kself_ref[0, 0].astype(jnp.float32)  # (1, dh)
+            l_self = jnp.sum(qf * ks, axis=-1, keepdims=True) / scale
+            update(l_self, jnp.ones(l_self.shape, bool),
+                   vself_ref[0, 0].astype(jnp.float32))
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("n_sel", "bk", "plan_mode",
-                                             "has_self", "window",
-                                             "interpret"))
-def decode_attend_fused(q, k, v, pos, cent, qpos, k_self, v_self, *,
-                        n_sel: int, bk: int, plan_mode: bool = False,
-                        has_self: bool = False, window: int = 0,
+@functools.partial(jax.jit, static_argnames=("bk", "has_self", "interpret"))
+def decode_attend_fused(q, k, v, pos, idx, qpos, k_self=None, v_self=None,
+                        *, bk: int, has_self: bool = False,
                         interpret: bool = False) -> jax.Array:
-    """Fused select+gather+attend. q (B,Hq,dh); k/v (B,Hkv,S,dh|dv);
-    pos (B,Hkv,S) int32; cent (B,Hkv,S/bk,dh); qpos (B,) int32;
-    k_self/v_self (B,Hkv,dh|dv) (ignored unless ``plan_mode`` and
-    ``has_self``). Returns (B,Hq,dv) in q's dtype."""
+    """Gather+attend over selected tiles. q (B,Hq,dh); k/v (B,Hkv,S,dh|dv);
+    pos (B,Hkv,S) int32 (entries > qpos are masked); idx (B,Hkv,c) int32
+    selected tile ids; qpos (B,) int32; k_self/v_self (B,Hkv,dh|dv), used
+    only with ``has_self``. Returns (B,Hq,dv) in q's dtype."""
     b, hq, dh = q.shape
     hkv, s = k.shape[1], k.shape[2]
     g = hq // hkv
     dv = v.shape[-1]
-    nkb = s // bk
-    if s % bk or nkb < n_sel:
+    n_sel = idx.shape[-1]
+    if s % bk or s // bk < n_sel:
         raise ValueError(f"cache length {s} needs {n_sel} whole {bk}-tiles")
+    if not has_self:
+        k_self = jnp.zeros((b, hkv, dh), k.dtype)
+        v_self = jnp.zeros((b, hkv, dv), v.dtype)
+
+    def tile(bi, hi, j, ix, qp):
+        return (bi, hi, ix[bi, hi, j], 0)
+
+    def row(bi, hi, j, ix, qp):
+        return (bi, hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv),
+        num_scalar_prefetch=2,
+        grid=(b, hkv, n_sel),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, qp: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, nkb, dh),
-                         lambda bi, hi, qp: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, s), lambda bi, hi, qp: (bi, hi, 0)),
-            # the caches stay in HBM; only selected tiles are DMA'd
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec((1, 1, dh), lambda bi, hi, qp: (bi, hi, 0)),
-            pl.BlockSpec((1, 1, dv), lambda bi, hi, qp: (bi, hi, 0)),
+            pl.BlockSpec((1, 1, g, dh), row),
+            pl.BlockSpec((1, 1, bk, dh), tile),
+            pl.BlockSpec((1, 1, bk, dv), tile),
+            pl.BlockSpec((1, 1, 1, 1, bk),
+                         lambda bi, hi, j, ix, qp: (bi, hi, ix[bi, hi, j],
+                                                    0, 0)),
+            pl.BlockSpec((1, 1, 1, dh), row),
+            pl.BlockSpec((1, 1, 1, dv), row),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda bi, hi, qp: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_sel * bk, dh), k.dtype),
-            pltpu.VMEM((n_sel * bk, dv), v.dtype),
-            pltpu.SemaphoreType.DMA((n_sel,)),
-            pltpu.SemaphoreType.DMA((n_sel,)),
-        ],
+        out_specs=pl.BlockSpec((1, 1, g, dv), row),
+        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
+                        pltpu.VMEM((g, 1), jnp.float32),
+                        pltpu.VMEM((g, dv), jnp.float32)],
     )
-    kern = functools.partial(_kernel, n_sel=n_sel, bk=bk, nkb=nkb, dh=dh,
-                             dv=dv, plan_mode=plan_mode, has_self=has_self,
-                             window=window)
     out = pl.pallas_call(
-        kern,
+        functools.partial(_kernel, n_sel=n_sel, has_self=has_self),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qpos.astype(jnp.int32), q.reshape(b, hkv, g, dh), cent,
-      pos.astype(jnp.int32), k, v, k_self, v_self)
+    )(idx.astype(jnp.int32), qpos.astype(jnp.int32),
+      q.reshape(b, hkv, g, dh), k, v,
+      pos.astype(jnp.int32).reshape(b, hkv, s // bk, 1, bk),
+      k_self.reshape(b, hkv, 1, dh), v_self.reshape(b, hkv, 1, dv))
     return out.reshape(b, hq, dv)

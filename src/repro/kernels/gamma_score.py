@@ -2,9 +2,12 @@
 
 gamma(A; sigma) = 1/(sigma nnz) * sum_{p,q in Inz} exp(-|p-q|^2 / sigma^2)
 over the nonzero coordinates. The O(nnz^2) sum is tiled: grid step (i, j)
-stages two (bn, 2) coordinate tiles into VMEM and accumulates the block's
-pairwise Gaussian sum into a scalar accumulator (TPU grids execute
-sequentially, so the (1, 1) output tile is a legal accumulator).
+stages the ``p`` tile as columns ``(bn, 2)`` and the ``q`` tile as rows
+``(2, bn)`` (the coordinates are passed in both layouts, so the pairwise
+``(bn, bn)`` block is a plain 2-D broadcast) and accumulates the block's
+Gaussian sum into a scalar in SMEM (TPU grids execute sequentially, so
+one SMEM word is a legal accumulator; a vector store of a scalar to VMEM
+is not).
 
 Production features over the bare tiled sum:
 
@@ -24,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(p_ref, q_ref, wp_ref, wq_ref, o_ref, *, sigma, symmetric):
@@ -32,13 +36,13 @@ def _kernel(p_ref, q_ref, wp_ref, wq_ref, o_ref, *, sigma, symmetric):
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[0, 0] = jnp.float32(0.0)
 
     def tile_sum():
-        a = p_ref[...].astype(jnp.float32)           # (bn, 2)
-        b = q_ref[...].astype(jnp.float32)           # (bn, 2)
-        w = wp_ref[:, 0][:, None] * wq_ref[:, 0][None, :]
-        d2 = ((a[:, None, :] - b[None, :, :]) ** 2).sum(-1)
+        a = p_ref[...]                                # (bn, 2) columns
+        b = q_ref[...]                                # (2, bn) rows
+        d2 = (a[:, 0:1] - b[0:1, :]) ** 2 + (a[:, 1:2] - b[1:2, :]) ** 2
+        w = wp_ref[...] * wq_ref[...]                 # (bn, 1) * (1, bn)
         return jnp.sum(w * jnp.exp(-d2 / (sigma * sigma)))
 
     if symmetric:
@@ -57,25 +61,26 @@ def gamma_pairs(coords: jax.Array, sigma: float, bn: int = 256,
                 symmetric: bool = False,
                 interpret: bool = False) -> jax.Array:
     """coords (nnz, 2) float32 (row, col) of nonzeros, padded to a bn
-    multiple — either with far sentinel rows (their pair terms vanish; the
-    legacy convention) or with any rows carrying zero ``weights``. Returns
-    the raw (weighted) pairwise sum; divide by sigma*nnz (or the weight
-    mass) for the gamma score."""
+    multiple (bn a multiple of 128) — either with far sentinel rows
+    (their pair terms vanish; the legacy convention) or with any rows
+    carrying zero ``weights``. Returns the raw (weighted) pairwise sum;
+    divide by sigma*nnz (or the weight mass) for the gamma score."""
     n = coords.shape[0]
     nb = n // bn
+    coords = coords.astype(jnp.float32)
     if weights is None:
         weights = jnp.ones((n,), jnp.float32)
-    w2 = weights.astype(jnp.float32)[:, None]        # (n, 1) for tiling
+    w = weights.astype(jnp.float32)
     return pl.pallas_call(
         functools.partial(_kernel, sigma=sigma, symmetric=symmetric),
         grid=(nb, nb),
         in_specs=[
             pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 2), lambda i, j: (j, 0)),
+            pl.BlockSpec((2, bn), lambda i, j: (0, j)),
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
-    )(coords, coords, w2, w2)[0, 0]
+    )(coords, coords.T, w[:, None], w[None, :])[0, 0]

@@ -1,15 +1,14 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True — the kernel
-body runs in Python for correctness validation; on TPU they compile to
-Mosaic. The wrappers handle batching (vmap over batch/head slices) and
-padding; the batch-grid SpMV sizes its tiles from the analytic cost
-model's hardware config (``core.costmodel.choose_tiles``).
+On the CPU backend the kernels run with ``interpret=True`` (the kernel
+body is evaluated by the Pallas interpreter, for correctness tests); on
+any other backend they compile to Mosaic, and nothing switches an
+accelerator to the interpreter. The wrappers handle batching (vmap over
+batch/head slices) and padding; the batch-grid SpMV sizes its tiles from
+the analytic cost model's hardware config (``core.costmodel
+.choose_tiles``).
 """
 from __future__ import annotations
-
-import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +27,6 @@ PALLAS_TRACE_COUNTS = {"batched": 0, "decode": 0}
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
-        return True
     return jax.default_backend() == "cpu"
 
 
@@ -60,19 +57,10 @@ _pallas_batched.interpret_only = _interpret
 
 def bsr_spmv(vals: jax.Array, col_idx: jax.Array, x: jax.Array,
              n: int | None = None) -> jax.Array:
-    """ELL-BSR SpMV/SpMM. x (n,) or (n, f); returns same leading length."""
-    n_rb, nbr, bs, _ = vals.shape
-    squeeze = x.ndim == 1
-    if squeeze:
-        x = x[:, None]
-    pad_rows = n_rb * bs - x.shape[0]
-    if pad_rows > 0:
-        x = jnp.pad(x, ((0, pad_rows), (0, 0)))
-    y = _bsr.bsr_spmv(vals.astype(jnp.float32), col_idx.astype(jnp.int32),
-                      x.astype(jnp.float32), interpret=_interpret())
-    if n is not None:
-        y = y[:n]
-    return y[:, 0] if squeeze else y
+    """ELL-BSR SpMV/SpMM of one matrix (the batch-grid kernel at B=1).
+    x (n,) or (n, f); returns the same leading length (``n`` if given)."""
+    y = bsr_spmv_batched(vals[None], col_idx[None], x[None])[0]
+    return y if n is None else y[:n]
 
 
 def bsr_spmv_batched(vals: jax.Array, col_idx: jax.Array, xs: jax.Array,
@@ -136,46 +124,40 @@ def block_attention(q, k_sorted, v_sorted, kpos, qpos, idx, *, bq, bk,
 
 
 def decode_attend_fused(q, k, v, pos, cent, qpos, *, n_sel, bk):
-    """Fused single-token cluster decode (plain caches).
-
-    Bitwise-identical to ``core.clusterkv.decode_select`` +
-    ``decode_attend`` — selection, tile gather, and the guarded softmax
-    run in ONE kernel and each selected tile streams HBM exactly once.
+    """Cluster decode over plain caches: ``core.clusterkv.decode_select``
+    picks the tiles, the Pallas kernel gathers and attends them.
     q (B,Hq,dh); k/v (B,Hkv,S,dh|dv); pos (B,Hkv,S); cent (B,Hkv,S/bk,dh);
     qpos scalar or (B,)."""
+    from repro.core import clusterkv as ckv
+
     PALLAS_TRACE_COUNTS["decode"] += 1
-    b, _, dh = q.shape
-    hkv = k.shape[1]
-    qp = jnp.broadcast_to(jnp.asarray(qpos, jnp.int32), (b,))
-    zk = jnp.zeros((b, hkv, dh), k.dtype)
-    zv = jnp.zeros((b, hkv, v.shape[-1]), v.dtype)
-    return _da.decode_attend_fused(q, k, v, pos, cent, qp, zk, zv,
-                                   n_sel=n_sel, bk=bk,
+    qp = jnp.broadcast_to(jnp.asarray(qpos, jnp.int32), (q.shape[0],))
+    idx = ckv.decode_select(q.astype(jnp.float32), cent.astype(jnp.float32),
+                            n_sel)
+    return _da.decode_attend_fused(q, k, v, pos, idx, qp, bk=bk,
                                    interpret=_interpret())
 
 
 @register_decode_backend("pallas")
 def _pallas_plan_decode(q, ks, vs, ps, cent, qpos, cfg, *,
                         k_self=None, v_self=None):
-    """Plan-ordered decode service attend via the fused Mosaic kernel.
+    """Plan-ordered decode service attend via the Pallas gather+attend
+    kernel. Same contract as the registered ``xla`` decode backend
+    (``models.attention._plan_decode_xla``), whose tile selection it
+    shares: hole tiles masked out of selection, local-window recency
+    boost, optional always-visible self column."""
+    from repro.core import clusterkv as ckv
 
-    Same contract as the registered ``xla`` decode backend
-    (``models.attention._plan_decode_xla``): hole tiles masked out of
-    selection, local-window recency boost, optional always-visible self
-    column."""
     PALLAS_TRACE_COUNTS["decode"] += 1
-    b, _, dh = q.shape
-    hkv, s = ks.shape[1], ks.shape[2]
+    s = ks.shape[2]
     bk = min(cfg.block_k, s)
-    has_self = k_self is not None
-    if not has_self:
-        k_self = jnp.zeros((b, hkv, dh), ks.dtype)
-        v_self = jnp.zeros((b, hkv, vs.shape[-1]), vs.dtype)
+    qp = qpos.astype(jnp.int32)
+    idx = ckv.plan_decode_select(q, ps, cent, qp,
+                                 min(cfg.decode_clusters, s // bk), bk,
+                                 cfg.local_window_blocks * bk)
     return _da.decode_attend_fused(
-        q, ks, vs, ps, cent, qpos.astype(jnp.int32), k_self, v_self,
-        n_sel=min(cfg.decode_clusters, s // bk), bk=bk,
-        plan_mode=True, has_self=has_self,
-        window=cfg.local_window_blocks * bk, interpret=_interpret())
+        q, ks, vs, ps, idx, qp, k_self, v_self, bk=bk,
+        has_self=k_self is not None, interpret=_interpret())
 
 
 _pallas_plan_decode.interpret_only = _interpret
@@ -206,17 +188,10 @@ def gamma_exact(rows: jax.Array, cols: jax.Array, sigma: float,
 
 def tsne_force(p_vals: jax.Array, col_idx: jax.Array, y: jax.Array,
                n: int | None = None) -> jax.Array:
-    """Blockwise t-SNE attractive force via the Pallas kernel (fused
-    gather, row-superblocked per the hardware config)."""
+    """Blockwise t-SNE attractive force via the Pallas kernel (source
+    segments gathered by the scalar-prefetched column index)."""
     from repro.kernels import tsne_force as _tf
-    n_rb, nbr, bs, _ = p_vals.shape
-    pad = n_rb * bs - y.shape[0]
-    yp = jnp.pad(y, ((0, max(pad, 0)), (0, 0))) if pad > 0 else y
-    n_cb = yp.shape[0] // bs
-    rbs, _, _ = choose_tiles((yp.shape[0], bs, 8, n_rb, n_cb, nbr),
-                             f=y.shape[-1])
     f = _tf.tsne_force(p_vals.astype(jnp.float32),
                        col_idx.astype(jnp.int32),
-                       yp.astype(jnp.float32), rbs=rbs,
-                       interpret=_interpret())
+                       y.astype(jnp.float32), interpret=_interpret())
     return f[:n] if n is not None else f

@@ -18,13 +18,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from repro.configs.base import SHAPES, ModelConfig, get_config
-from repro.core.costmodel import get_hardware
+from repro.core.costmodel import target_hardware
 from repro.models import model_api
 
-# per-chip constants from the knob-based hardware config (defaults are the
-# TPU v5e-like numbers from the brief; override with REPRO_HW_CONFIG /
-# costmodel.set_hardware before import)
-_HW = get_hardware()
+# per-chip constants of the chip being planned for (TPU v5e unless
+# REPRO_HW_CONFIG names a knob file before import)
+_HW = target_hardware()
 PEAK_FLOPS = _HW.peak_flops  # bf16
 HBM_BW = _HW.hbm_bw          # bytes/s
 LINK_BW = _HW.link_bw        # bytes/s per ICI link

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_api
 from repro.train import trainer
 
@@ -71,6 +72,7 @@ def main():
     ap.add_argument("--report", default=None,
                     help="write the service JSON report here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     mod = model_api.module_for(cfg)

@@ -3,9 +3,8 @@
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --reduced \
       --steps 50 --batch 8 --seq 128
 
-On a real TPU fleet the same entrypoint initializes jax.distributed and
-builds the production mesh; on this CPU container ``--reduced`` runs the
-reduced config end-to-end (single device) and ``--dry-run`` only lowers.
+It runs on the devices of one host (nothing here initializes
+``jax.distributed``); ``--reduced`` runs the reduced config end-to-end.
 
 Distributed-optimization environment (set before jax init): the launcher
 exports the XLA flags that enable latency-hiding scheduling so collectives
@@ -36,6 +35,7 @@ import numpy as np
 
 from repro.checkpoint.ckpt import Checkpointer
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import pipeline
 from repro.launch.ft import Supervisor
 from repro.models import model_api
@@ -58,6 +58,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     opt = make_optimizer(cfg.optimizer, lr=args.lr, warmup=max(args.steps // 20, 1),

@@ -243,7 +243,7 @@ def clusterkv_decode(q, k, v, kpos, qpos, cfg: ClusterKVConfig):
 
     ``cfg.use_pallas`` routes the select+gather+attend chain through the
     fused Mosaic kernel (``kernels/decode_attend.py``) instead of the two
-    unfused XLA ops — bitwise-identical output, selected tiles stream
+    unfused XLA ops — equal to float32 rounding, selected tiles stream
     from HBM exactly once."""
     b, hq, dh = q.shape
     hkv, s = k.shape[1], k.shape[2]
@@ -328,17 +328,8 @@ def _plan_decode_xla(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
 
     pt = ps.reshape(b, hkv, nkb, bk)
     qp = qpos.astype(jnp.int32)                       # (B,)
-    live = pt <= qp[:, None, None, None]              # causal AND not-a-hole
-    tile_has = live.any(-1)                           # (B,Hkv,nkb)
-    qg = q.reshape(b, hkv, g, dh).mean(axis=2).astype(jnp.float32)
-    # multiply+reduce, not einsum: batching-stable M=1 contraction (see
-    # ckv.decode_select) so the fused kernel scores bitwise-identically
-    scores = jnp.sum(qg[:, :, None, :] * cent.astype(jnp.float32), -1)
-    scores = jnp.where(tile_has, scores, NEG_INF)
-    recent = jnp.where(live, pt, -1).max(-1)
-    near = recent >= (qp[:, None, None] - cfg.local_window_blocks * bk)
-    scores = jnp.where(near & tile_has, scores + 1e4, scores)
-    _, idx = jax.lax.top_k(scores, n_sel)             # (B,Hkv,n_sel)
+    idx = ckv.plan_decode_select(q, ps, cent, qp, n_sel, bk,
+                                 cfg.local_window_blocks * bk)
 
     kb = ks.reshape(b, hkv, nkb, bk, dh)
     vb = vs.reshape(b, hkv, nkb, bk, dv)
@@ -359,7 +350,7 @@ def _plan_decode_xla(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
         # guarded (see ckv.masked_softmax): a just-admitted slot can select
         # nothing but holes when no self column rides along
         w = ckv.masked_softmax(logit, psel[None, :] <= qp_)
-        return ckv.decode_combine(w, vsel.astype(jnp.float32)).astype(q.dtype)
+        return (w @ vsel.astype(jnp.float32)).astype(q.dtype)
 
     out = jax.vmap(jax.vmap(per_h, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None)),
                    in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0))(

@@ -1,6 +1,7 @@
 """Analytic cost model + hardware-config knobs + analytic-first autotune."""
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -113,15 +114,49 @@ def test_exchange_cost_monotone_and_none_passthrough():
 def test_choose_tiles_contracts():
     key = (512, 16, 8, 32, 32, 6)
     rbs, chunk, fc = costmodel.choose_tiles(key, f=4)
-    assert chunk == 6          # full ELL width always (bit parity)
-    assert fc == 4
+    assert chunk == 6          # the whole ELL width fits the budget
+    assert fc == 128           # feature tiles are whole 128-lane tiles
     assert rbs in (1, 2, 4, 8) and rbs <= 8
-    # a starved VMEM budget shrinks the feature tile and superblock
+    # a starved VMEM budget splits the slot sum and shrinks the superblock;
+    # the feature tile never drops below one lane tile
     tiny = HardwareConfig(vmem_bytes=64 * 1024)
     rbs_t, chunk_t, fc_t = costmodel.choose_tiles(key, f=16, hw=tiny)
-    assert chunk_t == 6
-    assert fc_t < 16
+    assert chunk_t < 6
+    assert fc_t == 128
     assert rbs_t <= rbs
+
+
+def test_choose_tiles_counts_lane_padding():
+    """A (16, 4) charge segment occupies a (16, 128) VMEM tile: the budget
+    holds what the padded tiles take, not the nominal bytes."""
+    hw = HardwareConfig(vmem_bytes=256 * 1024)
+    bs, nbr = 16, 64
+    key = (1024, bs, 8, 64, 64, nbr)
+    rbs, chunk, fc = costmodel.choose_tiles(key, f=4, hw=hw)
+    pad = costmodel._vmem_bytes
+    q = 128 // bs
+    per_slot = 2 * pad(bs // q, bs * q) + pad(bs, fc)
+    used = rbs * (chunk * per_slot + 2 * pad(bs, fc))
+    assert pad(bs, 4) == pad(bs, 128) == bs * 128 * 4
+    assert used <= hw.vmem_bytes / 2
+    assert chunk < nbr         # nominal bytes would have kept every slot
+
+
+def test_get_hardware_by_device_kind(monkeypatch):
+    class Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    monkeypatch.delenv("REPRO_HW_CONFIG", raising=False)
+    for platform, kind, want in [("tpu", "TPU v5 lite", "tpu-v5e"),
+                                 ("cpu", "cpu", "tpu-v5e")]:
+        monkeypatch.setattr(costmodel, "_HARDWARE", None)
+        monkeypatch.setattr(jax, "devices", lambda: [Dev(platform, kind)])
+        assert costmodel.get_hardware().name == want
+    monkeypatch.setattr(costmodel, "_HARDWARE", None)
+    monkeypatch.setattr(jax, "devices", lambda: [Dev("tpu", "TPU v9")])
+    with pytest.raises(ValueError, match="no hardware knobs"):
+        costmodel.get_hardware()
 
 
 # -- analytic-first autotune ------------------------------------------------
@@ -180,6 +215,31 @@ def test_probe_backends_skips_interpret_pallas():
                                         iters=1, warmup=0,
                                         include_interpret=True)
     assert "pallas" in times_inc         # escape hatch still times it
+
+
+def test_probe_propagates_faults_skips_refusals(monkeypatch):
+    """Only the typed NotApplicable refusal is skipped; a backend that
+    fails (a kernel the chip's compiler refuses) is an error, never a
+    silent fall back to another path."""
+    from repro.core import registry
+
+    def broken(plan, x, **_kw):
+        raise RuntimeError("compile refused")
+
+    def refuses(plan, x, **_kw):
+        raise registry.NotApplicable("does not apply")
+
+    registry._ensure_defaults()
+    monkeypatch.setitem(registry._BACKENDS, "broken", broken)
+    monkeypatch.setitem(registry._BACKENDS, "refuses", refuses)
+    plan = _plan(n=128)
+    x = jnp.ones(plan.n, jnp.float32)
+    times = autotune.probe_backends(plan, x, backends=("bsr", "refuses"),
+                                    iters=1, warmup=0)
+    assert set(times) == {"bsr"}
+    with pytest.raises(RuntimeError, match="compile refused"):
+        autotune.probe_backends(plan, x, backends=("bsr", "broken"),
+                                iters=1, warmup=0)
 
 
 def _decode_feat(**kw):

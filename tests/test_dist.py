@@ -17,6 +17,7 @@ def run_sub(body: str, devices: int = 8, timeout: int = 900):
         import sys
         sys.path.insert(0, {SRC!r})
         import jax
+        from repro.compat import make_mesh
         assert jax.device_count() == {devices}
     """) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -31,7 +32,7 @@ def test_spmv_sharded_matches_dense():
         from repro.core.blocksparse import random_bsr
         from repro.core.dist import spmv_sharded
         from repro.core import interact
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         bsr = random_bsr(0, 512, 32, 4)      # n_rb=16 divisible by 8
         x = jnp.asarray(np.random.default_rng(0).standard_normal(512), jnp.float32)
         y = spmv_sharded(bsr, x, mesh)
@@ -48,7 +49,7 @@ def test_spmv_sharded_pads_nondivisible():
         from repro.core.blocksparse import random_bsr
         from repro.core.dist import spmv_sharded
         from repro.api import InteractionPlan
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         bsr = random_bsr(0, 320, 32, 4)      # n_rb=10, pads to 16
         x = jnp.asarray(np.random.default_rng(0).standard_normal(320), jnp.float32)
         y = spmv_sharded(bsr, x, mesh)
@@ -68,7 +69,7 @@ def test_clusterkv_decode_sharded_matches_local():
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs.base import ClusterKVConfig
         from repro.models import attention as attn
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rng = np.random.default_rng(0)
         B,Hq,Hkv,S,dh = 1,4,2,256,16
         q = jnp.asarray(rng.standard_normal((B,Hq,dh)), jnp.float32)
@@ -98,7 +99,7 @@ def test_small_mesh_train_lower_and_run():
         from jax.sharding import PartitionSpec as P
 
         cfg = reduced_config("granite-moe-3b-a800m")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         opt = make_optimizer("adamw")
         step, _ = trainer.make_train_step(cfg, mesh, "flash", optimizer=opt)
         params, _ = model_api.init(cfg, jax.random.PRNGKey(0))
@@ -128,8 +129,8 @@ def test_elastic_checkpoint_reshard():
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.ckpt import Checkpointer
-        mesh4 = jax.make_mesh((4,), ("data",))
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh4 = make_mesh((4,), ("data",))
+        mesh2 = make_mesh((2, 2), ("data", "model"))
         t = {"w": jnp.arange(64.0).reshape(8, 8)}
         t4 = jax.device_put(t, {"w": NamedSharding(mesh4, P("data"))})
         ck = Checkpointer(tempfile.mkdtemp())
@@ -155,7 +156,7 @@ def test_moe_ep_all_to_all_matches_tp():
         # generous capacity so neither path drops tokens (drop sets differ
         # between shard-local and global capacity accounting)
         cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         key = jax.random.PRNGKey(0)
         p, _ = moe_mod.init_moe(key, cfg)
         x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, cfg.d_model))

@@ -9,7 +9,6 @@ from repro.core.blocksparse import random_bsr
 from repro.core.interact import spmv_bsr_ml_batched
 from repro.kernels import ops, ref
 from repro.kernels.block_attention import block_attention as ba_kernel
-from repro.kernels.bsr_spmv import bsr_spmv as bsr_kernel
 from repro.kernels.bsr_spmv import bsr_spmv_batched as batch_kernel
 from repro.kernels.gamma_score import gamma_pairs
 
@@ -23,7 +22,8 @@ def test_bsr_spmv_shapes(n, bs, nbr, f):
     x = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
     pad = bsr.n_rb * bs - n
     xp = jnp.pad(x, ((0, pad), (0, 0)))
-    got = bsr_kernel(bsr.vals, bsr.col_idx, xp, interpret=True)
+    got = batch_kernel(bsr.vals[None], bsr.col_idx[None], xp[None],
+                       interpret=True)[0]
     want = ref.bsr_spmv_ref(bsr.vals, bsr.col_idx, xp)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -97,7 +97,14 @@ def test_gamma_pairs_shapes(nnz, bn):
     assert got == pytest.approx(want, rel=1e-4)
 
 
-# -- batch-grid kernel: edge shapes, all bit-matching bsr_ml batched --------
+# -- batch-grid kernel: edge shapes, all matching bsr_ml batched ------------
+#
+# The kernel splits the ELL slot sum into chunks and contracts each tile on
+# the MXU, where the XLA ``bsr_ml`` path sums every slot in one
+# batch_matmul: the same products, associated differently. float32 rounds
+# each add at ~6e-8 relative, so with a handful of O(1) slots the two agree
+# to ~1e-6; 1e-5 is the bound (bitwise parity would forbid the chip's form).
+_TOL = dict(rtol=1e-5, atol=1e-5)
 
 
 def _random_batch(B, n_cb, bs, nbr, seed=0):
@@ -110,21 +117,25 @@ def _random_batch(B, n_cb, bs, nbr, seed=0):
             jnp.asarray(np.stack(idxs), jnp.int32))
 
 
-@pytest.mark.parametrize("B,n_cb,bs,nbr,f,rbs,fc", [
+@pytest.mark.parametrize("B,n_cb,bs,nbr,f,rbs,fct", [
     (1, 8, 16, 4, 1, 1, None),     # degenerate single member
     (3, 8, 16, 4, 1, 4, None),     # row-superblocked, scalar charges
     (3, 8, 16, 4, 3, 2, 2),        # f not a multiple of the feature tile
     (2, 8, 16, 4, 5, 3, 4),        # rbs not dividing n_rb (row padding)
+    (2, 8, 32, 5, 300, 2, 1),      # three 128-lane feature tiles
 ])
-def test_batch_kernel_bit_matches_bsr_ml(B, n_cb, bs, nbr, f, rbs, fc):
+def test_batch_kernel_bit_matches_bsr_ml(B, n_cb, bs, nbr, f, rbs, fct):
+    """``fct`` counts 128-lane feature tiles (None: the default tile)."""
     vals, col_idx = _random_batch(B, n_cb, bs, nbr)
     rng = np.random.default_rng(9)
     shape = (B, n_cb * bs) if f == 1 else (B, n_cb * bs, f)
     xs = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    got = batch_kernel(vals, col_idx, xs, rbs=rbs, fc=fc, interpret=True)
+    fc = 128 * (fct or 1)
+    got = batch_kernel(vals, col_idx, xs, rbs=rbs, chunk=3, fc=fc,
+                       interpret=True)
     want = spmv_bsr_ml_batched(vals, col_idx, xs, 8)
     assert got.dtype == want.dtype and got.shape == want.shape
-    assert bool(jnp.array_equal(got, want))      # bitwise, not approx
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
 
 
 def _holey_batch():
@@ -148,7 +159,7 @@ def test_batch_backend_holes_and_padding_bit_match():
                                        "apply")
         got = api._batch_apply_kernel(pb.spec, pb.data, xs, "pallas",
                                       "apply")
-        assert bool(jnp.array_equal(got, want))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
 
 
 def test_single_plan_pallas_dead_slots_stay_zero():
@@ -171,8 +182,8 @@ def test_single_plan_pallas_dead_slots_stay_zero():
 
 
 def test_batched_pallas_64_members_one_kernel():
-    """64-member PlanBatch matvec: ONE compiled kernel (trace-counted) and
-    bit-identical to the bsr_ml batched backend."""
+    """64-member PlanBatch matvec: ONE compiled kernel (trace-counted),
+    matching the bsr_ml batched backend to float32 rounding."""
     rng = np.random.default_rng(6)
     xs = [rng.standard_normal((64, 8)).astype(np.float32)
           for _ in range(64)]
@@ -184,7 +195,7 @@ def test_batched_pallas_64_members_one_kernel():
         got = pb.matvec(x, backend="pallas")
     assert ops.PALLAS_TRACE_COUNTS["batched"] == 1
     want = pb.matvec(x, backend="bsr_ml")
-    assert bool(jnp.array_equal(got, want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
 
 
 @pytest.mark.parametrize("n,bs,k,d", [(256, 16, 6, 2), (512, 32, 10, 3)])
@@ -215,9 +226,20 @@ def test_tsne_force_kernel(n, bs, k, d):
 # fused decode attention (kernels/decode_attend.py)
 # ---------------------------------------------------------------------------
 #
-# The references are the JITTED pure-JAX ops: the decode service calls them
-# inside the engine's jitted tick, and on XLA:CPU an eagerly-executed dot
-# can round differently from its jitted fusion — jit is the contract.
+# The references are the JITTED pure-JAX ops the decode service would
+# otherwise run. Both attend the same selected tiles (the selection is
+# shared code); the kernel folds them into an online softmax tile by tile
+# where the reference takes one softmax over the concatenated selection, so
+# the float32 results agree to rounding (~1e-6 here; bound 1e-5) and a
+# bfloat16 output to one rounding of the output dtype (2^-8 relative).
+
+
+def _assert_decode_close(got, want):
+    assert got.dtype == want.dtype
+    tol = 1e-5 if got.dtype == jnp.float32 else 2 ** -7
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
 
 
 def _plain_decode_case(seed, B, hq, hkv, S, dh, bk, dtype):
@@ -243,7 +265,7 @@ def _plain_decode_case(seed, B, hq, hkv, S, dh, bk, dtype):
     (6, 2, jnp.bfloat16),
 ])
 def test_decode_fused_bitwise_plain(hq, hkv, dtype):
-    """Fused kernel == jitted decode_select + decode_attend, bitwise."""
+    """Fused kernel == jitted decode_select + decode_attend."""
     from repro.core import clusterkv as ckv
     S, dh, bk, n_sel = 128, 32, 32, 2
     q, k, v, pos, cent = _plain_decode_case(11, 2, hq, hkv, S, dh, bk,
@@ -253,8 +275,7 @@ def test_decode_fused_bitwise_plain(hq, hkv, dtype):
                                       n_sel=n_sel, bk=bk)
         idx = ckv.decode_select(q, cent.astype(jnp.float32), n_sel)
         want = ckv.decode_attend(q, k, v, pos, qpos, idx, bk)
-        assert got.dtype == want.dtype
-        assert bool(jnp.array_equal(got, want)), qpos
+        _assert_decode_close(got, want)
 
 
 @pytest.mark.parametrize("hq,hkv,has_self", [
@@ -267,7 +288,7 @@ def test_decode_fused_bitwise_plain(hq, hkv, dtype):
 def test_decode_fused_bitwise_plan_holey(hq, hkv, has_self):
     """Plan mode vs the jitted xla decode backend over capacity-padded
     caches: hole slots (pos == INT32_MAX) carry garbage k/v and must be
-    bitwise-invisible; the self column must ride along untouched."""
+    invisible; the self column must ride along untouched."""
     import functools
 
     from repro.configs.base import ClusterKVConfig
@@ -302,8 +323,7 @@ def test_decode_fused_bitwise_plan_holey(hq, hkv, has_self):
     else:
         want = ref(q, ks, vs, ps, cent, qpos)
         got = attn.clusterkv_plan_decode(q, ks, vs, ps, cent, qpos, cfg)
-    assert got.dtype == want.dtype
-    assert bool(jnp.array_equal(got, want))
+    _assert_decode_close(got, want)
 
 
 def test_decode_fused_one_trace():

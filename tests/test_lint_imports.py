@@ -18,6 +18,7 @@ FORBIDDEN = (
     r"\bAbstractMesh\b",
     r"\blax\.axis_size\b",
     r"\bcheck_rep\b",
+    r"jax\.make_mesh",          # meshes come from compat (AxisType.Auto)
 )
 
 

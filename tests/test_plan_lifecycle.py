@@ -11,6 +11,7 @@ import pytest
 
 from repro import api
 from repro.checkpoint.ckpt import Checkpointer
+from repro.compat import make_mesh
 from repro.core import blocksparse, hierarchy, interact, measures
 from repro.core.ordering import stable_partial_reorder
 from repro.data.pipeline import feature_mixture
@@ -492,7 +493,7 @@ def test_restore_plan_mesh_validation(plan):
         ck.restore_plan(mesh="bogus")
     with pytest.raises(TypeError, match="Mesh or 'auto'"):
         ck.restore_plan(mesh=3)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     with pytest.raises(ValueError, match="no axis 'model'"):
         ck.restore_plan(mesh=mesh, axis="model")
     sp, _ = ck.restore_plan(mesh=mesh)       # happy path still works

@@ -13,9 +13,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys
 sys.path.insert(0, {SRC!r})
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from repro.launch.pp import pipeline_apply
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 rng = np.random.default_rng(0)
 S, B, D = 4, 8, 16
 w = jnp.asarray(rng.standard_normal((S, D, D)) / np.sqrt(D), jnp.float32)
