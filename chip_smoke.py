@@ -261,9 +261,7 @@ def decode_phase(cfg, *, slots: int = 4, max_seq: int = 4096,
             raise AssertionError(f"request {r.rid} output {r.output}")
     _say(f"decode: bf16 service, decode_backend=pallas, "
          f"{rep['decode_traces']} decode trace, {rep['tokens_out']} tokens; "
-         f"smoke reading, not a benchmark: wall {wall:.1f} s with compiles, "
-         f"device tick {rep['device_tick_s']:.2f} s, host claims "
-         f"{rep['host_claim_s']:.2f} s")
+         f"smoke reading, not a benchmark: wall {wall:.1f} s with compiles")
 
     full = dataclasses.replace(ckv, decode_clusters=max_seq // block_k)
     c32 = cfg.with_(dtype="float32", param_dtype="float32", clusterkv=full)

@@ -116,6 +116,7 @@ from repro.core.registry import (NotApplicable,  # noqa: F401
                                  register_batched_backend,
                                  register_preconditioner)
 from repro.core.shardplan import ShardedPlan, shard  # noqa: F401
+from repro.spans import span
 
 __all__ = [
     "PlanConfig", "PlanSpec", "PlanData", "InteractionPlan", "PlanBatch",
@@ -427,31 +428,34 @@ class InteractionPlan:
         tree = None
         embedding = None
         emean = eaxes = None
-        if pi is None and x is not None:
-            x = np.asarray(x, np.float32)
-            if config.ordering == "dual_tree":
-                d = min(config.d, x.shape[1])
-                emean, eaxes = (np.asarray(a) for a in
-                                pca_map(jnp.asarray(x), d))
-                embedding = np.asarray(apply_pca_map(
-                    jnp.asarray(x), jnp.asarray(emean), jnp.asarray(eaxes)))
-                tree = build_tree(embedding, bits=config.bits,
-                                  leaf_size=config.leaf_size)
-                pi = tree.perm
-            else:
-                pi = ordering_mod.compute_ordering(
-                    config.ordering, x, rows, cols, seed=config.seed)
-        if pi is None:
-            pi = np.arange(n)
-        pi = np.asarray(pi)
-        inv = np.empty_like(pi)
-        inv[pi] = np.arange(n)
+        with span("build.order"):
+            if pi is None and x is not None:
+                x = np.asarray(x, np.float32)
+                if config.ordering == "dual_tree":
+                    d = min(config.d, x.shape[1])
+                    emean, eaxes = (np.asarray(a) for a in
+                                    pca_map(jnp.asarray(x), d))
+                    embedding = np.asarray(apply_pca_map(
+                        jnp.asarray(x), jnp.asarray(emean),
+                        jnp.asarray(eaxes)))
+                    tree = build_tree(embedding, bits=config.bits,
+                                      leaf_size=config.leaf_size)
+                    pi = tree.perm
+                else:
+                    pi = ordering_mod.compute_ordering(
+                        config.ordering, x, rows, cols, seed=config.seed)
+            if pi is None:
+                pi = np.arange(n)
+            pi = np.asarray(pi)
+            inv = np.empty_like(pi)
+            inv[pi] = np.arange(n)
 
-        r2, c2 = ordering_mod.apply_ordering(rows, cols, pi)
         sigma = sigma if sigma is not None else max(config.k / 2.0, 1.0)
-        bsr = (build_bsr(r2, c2, vals, n, bs=config.bs, sb=config.sb,
-                         max_nbr=max_nbr, slack=config.ell_slack)
-               if with_bsr else None)
+        with span("build.tiles"):
+            r2, c2 = ordering_mod.apply_ordering(rows, cols, pi)
+            bsr = (build_bsr(r2, c2, vals, n, bs=config.bs, sb=config.sb,
+                             max_nbr=max_nbr, slack=config.ell_slack)
+                   if with_bsr else None)
         host = _PlanHost(pi=pi, inv=inv, coo=(r2, c2, vals), tree=tree,
                          embedding=embedding, sigma=sigma,
                          embed_mean=emean, embed_axes=eaxes,
@@ -932,47 +936,50 @@ def build_plan(x, *, k: int = 16, ordering: str = "dual_tree", bs: int = 32,
         if config.symmetrize:
             raise ValueError("symmetrize crosses the target/source index "
                              "spaces; not meaningful with fixed sources")
-    xd = jnp.asarray(x)
-    sd = xd if sources is None else jnp.asarray(sources)
-    rows, cols, d2 = knn.knn_coo(xd, sd, config.k,
-                                 exclude_self=sources is None)
-    rows = np.asarray(rows)
-    cols = np.asarray(cols)
-    d2 = np.asarray(d2)
+    with span("build", n=n, k=config.k):
+        with span("build.knn"):
+            xd = jnp.asarray(x)
+            sd = xd if sources is None else jnp.asarray(sources)
+            rows, cols, d2 = knn.knn_coo(xd, sd, config.k,
+                                         exclude_self=sources is None)
+            rows = np.asarray(rows)
+            cols = np.asarray(cols)
+            d2 = np.asarray(d2)
 
-    if config.symmetrize:
-        # pattern-level symmetrization (first occurrence wins, like the
-        # paper's Fig. 2 interaction patterns) — before values, so a
-        # callable sees the symmetrized edge list
-        rows, cols, d2 = _symmetrize_pattern(rows, cols, d2, n)
+        if config.symmetrize:
+            # pattern-level symmetrization (first occurrence wins, like the
+            # paper's Fig. 2 interaction patterns) — before values, so a
+            # callable sees the symmetrized edge list
+            rows, cols, d2 = _symmetrize_pattern(rows, cols, d2, n)
 
-    if values is None:
-        vals = np.ones(len(rows), np.float32)
-    elif callable(values):
-        vals = np.asarray(values(rows, cols, d2), np.float32)
-    else:
-        vals = np.asarray(values, np.float32)
-        if vals.shape[0] != len(rows):
-            raise ValueError(
-                f"values has {vals.shape[0]} entries, pattern has "
-                f"{len(rows)} edges (symmetrize={config.symmetrize})")
+        if values is None:
+            vals = np.ones(len(rows), np.float32)
+        elif callable(values):
+            vals = np.asarray(values(rows, cols, d2), np.float32)
+        else:
+            vals = np.asarray(values, np.float32)
+            if vals.shape[0] != len(rows):
+                raise ValueError(
+                    f"values has {vals.shape[0]} entries, pattern has "
+                    f"{len(rows)} edges (symmetrize={config.symmetrize})")
 
-    plan = InteractionPlan.from_coo(rows, cols, vals, n, x=x, config=config,
-                                    sigma=sigma, with_bsr=with_bsr,
-                                    _symmetrized=True)
-    plan.host.pattern_from_knn = True
-    plan.host.sources = sources
-    if callable(values):
-        plan.host.values_mode = "fn"
-        plan.host.values_fn = values
-    elif values is not None:
-        plan.host.values_mode = "static"
-    if capacity is not None:
-        if capacity < n:
-            raise ValueError(f"capacity={capacity} < n={n} points")
-        if capacity > n:
-            plan = _spread_holes(_grow_plan(plan, capacity))
-    return plan
+        plan = InteractionPlan.from_coo(rows, cols, vals, n, x=x,
+                                        config=config, sigma=sigma,
+                                        with_bsr=with_bsr, _symmetrized=True)
+        plan.host.pattern_from_knn = True
+        plan.host.sources = sources
+        if callable(values):
+            plan.host.values_mode = "fn"
+            plan.host.values_fn = values
+        elif values is not None:
+            plan.host.values_mode = "static"
+        if capacity is not None:
+            if capacity < n:
+                raise ValueError(f"capacity={capacity} < n={n} points")
+            if capacity > n:
+                with span("build.grow", capacity=capacity):
+                    plan = _spread_holes(_grow_plan(plan, capacity))
+        return plan
 
 
 # ---------------------------------------------------------------------------
@@ -2595,4 +2602,5 @@ def build_plan_batch(xs, *, k: int = 16, ordering: str = "dual_tree",
                         with_bsr=with_bsr,
                         capacity=cap if cap > x.shape[0] else None)
              for x in members]
-    return PlanBatch.from_plans(plans, capacity=cap)
+    with span("build.stack", members=len(plans)):
+        return PlanBatch.from_plans(plans, capacity=cap)

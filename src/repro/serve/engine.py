@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -37,6 +36,7 @@ from repro.core import clusterkv as ckv
 from repro.models.sharding import NO_SHARD
 from repro.serve.session import Session, SessionStore
 from repro.serve.streaming import LockstepInserter
+from repro.spans import span
 from repro.train.serve_loop import Engine, Request
 
 _BIG = np.iinfo(np.int32).max
@@ -99,12 +99,6 @@ class ClusterKVEngine(Engine):
         self.plan_prefill = plan_prefill
         self.decode_traces = 0
         self.tokens_out = 0
-        self._tick_time = 0.0
-        # plan-mode tick split: jitted decode+land dispatch vs the host
-        # inserter's claim-and-mutate pass (bench_serve gates on the host
-        # share staying small — the tick should be kernel-bound)
-        self._device_time = 0.0
-        self._claim_time = 0.0
         self._pf_plan: Dict[int, callable] = {}
         backend = "clusterkv" if mode == "percall" else "flash"
         super().__init__(cfg, params, slots=slots, max_seq=max_seq,
@@ -182,31 +176,39 @@ class ClusterKVEngine(Engine):
             raise ValueError(
                 f"prefill bucket {blen} must exceed knn={self.knn} (spec "
                 "unification pins every member's k to knn)")
-        k_np = np.asarray(cache_1["k"][:, 0], np.float32)   # (L,Hkv,blen,dh)
-        v_np = np.asarray(cache_1["v"][:, 0], np.float32)
+        # the prefill batch is one request, so these are the slot's bytes
+        with span("admit.kv_out",
+                  bytes=cache_1["k"].nbytes + cache_1["v"].nbytes):
+            # (L, Hkv, blen, dh)
+            k_np = np.asarray(cache_1["k"][:, 0], np.float32)
+            v_np = np.asarray(cache_1["v"][:, 0], np.float32)
         S = self.max_seq
-        plans = [ckv.kv_plan_batch(jnp.asarray(k_np[l]),
-                                   d=self.cfg.clusterkv.embed_dim,
-                                   knn=self.knn, capacity=S)
-                 for l in range(self.L)]
-        # physical row p < blen holds the key of time position p; tail rows
-        # are capacity holes (INT32_MAX position sentinel)
-        pi = np.stack([np.asarray(pb.data.pi) for pb in plans])  # (L,Hkv,S)
-        k_pad = np.zeros((self.L, self.Hkv, S, self.dh), np.float32)
-        v_pad = np.zeros((self.L, self.Hkv, S, self.dh), np.float32)
-        k_pad[:, :, :blen], v_pad[:, :, :blen] = k_np, v_np
-        ks = np.take_along_axis(k_pad, pi[..., None], axis=2)
-        vs = np.take_along_axis(v_pad, pi[..., None], axis=2)
-        ps = np.where(pi < blen, pi, _BIG).astype(np.int32)
-        cent = ks.reshape(self.L, self.Hkv, S // self.bk, self.bk,
-                          self.dh).mean(3)
-        dt = self.pstate["ks"].dtype
-        self.pstate = {
-            "ks": self.pstate["ks"].at[:, s].set(jnp.asarray(ks, dt)),
-            "vs": self.pstate["vs"].at[:, s].set(jnp.asarray(vs, dt)),
-            "ps": self.pstate["ps"].at[:, s].set(jnp.asarray(ps)),
-            "cent": self.pstate["cent"].at[:, s].set(jnp.asarray(cent)),
-        }
+        with span("admit.plans", layers=self.L):
+            plans = [ckv.kv_plan_batch(jnp.asarray(k_np[l]),
+                                       d=self.cfg.clusterkv.embed_dim,
+                                       knn=self.knn, capacity=S)
+                     for l in range(self.L)]
+        with span("admit.kv_in"):
+            # physical row p < blen holds the key of time position p; tail
+            # rows are capacity holes (INT32_MAX position sentinel)
+            pi = np.stack([np.asarray(pb.data.pi)
+                           for pb in plans])                   # (L,Hkv,S)
+            k_pad = np.zeros((self.L, self.Hkv, S, self.dh), np.float32)
+            v_pad = np.zeros((self.L, self.Hkv, S, self.dh), np.float32)
+            k_pad[:, :, :blen], v_pad[:, :, :blen] = k_np, v_np
+            ks = np.take_along_axis(k_pad, pi[..., None], axis=2)
+            vs = np.take_along_axis(v_pad, pi[..., None], axis=2)
+            ps = np.where(pi < blen, pi, _BIG).astype(np.int32)
+            cent = ks.reshape(self.L, self.Hkv, S // self.bk, self.bk,
+                              self.dh).mean(3)
+            dt = self.pstate["ks"].dtype
+            self.pstate = {
+                "ks": self.pstate["ks"].at[:, s].set(jnp.asarray(ks, dt)),
+                "vs": self.pstate["vs"].at[:, s].set(jnp.asarray(vs, dt)),
+                "ps": self.pstate["ps"].at[:, s].set(jnp.asarray(ps)),
+                "cent": self.pstate["cent"].at[:, s].set(
+                    jnp.asarray(cent)),
+            }
         self._pend_phys[:, s] = -1
         self._plan_gen[s] = 0
         self.inserter.attach(s, plans, generation=0)
@@ -248,10 +250,8 @@ class ClusterKVEngine(Engine):
     # -- the tick -----------------------------------------------------------
 
     def step(self) -> int:
-        t0 = time.time()
         n = self._plan_step() if self.mode == "plan" else super().step()
         self.tokens_out += n
-        self._tick_time += time.time() - t0
         return n
 
     def _pend_slots(self) -> np.ndarray:
@@ -275,35 +275,35 @@ class ClusterKVEngine(Engine):
         active = [s for s, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return 0
-        tokens = np.zeros((self.slots, 1), np.int32)
-        for s in active:
-            tokens[s, 0] = self.slot_req[s].output[-1]
-        pend = {"k": self._pend_k, "v": self._pend_v,
-                "slot": jnp.asarray(self._pend_slots()),
-                "pos": jnp.asarray(self._pend_pos)}
-        t0 = time.time()
-        logits, self.pstate, nk, nv = self._plan_decode(
-            self.params, self.pstate, pend, jnp.asarray(tokens),
-            jnp.asarray(self.slot_pos))
-        nxt = np.asarray(jnp.argmax(logits, -1))
-        self._device_time += time.time() - t0
-        # stream this tick's keys into the session plans: the host claims
-        # each one's Morton-leaf slot now; the device lands it next tick
-        t0 = time.time()
-        phys = self.inserter.insert(
-            active, nk,
-            generations={s: self._plan_gen[s] for s in active})
-        self._claim_time += time.time() - t0
-        self._pend_phys = phys
-        self._pend_k, self._pend_v = nk, nv
-        self._pend_pos = self.slot_pos.copy()
-        for s in active:
-            sess = self._slot_sess[s]
-            sess.phys_hist[int(self.slot_pos[s])] = phys[:, s, :].copy()
-            self.slot_pos[s] += 1
-            self.slot_req[s].output.append(int(nxt[s]))
-        self.store.counters["inserts"] += len(active)
-        self.ticks += 1
+        with span("decode", active=len(active)):
+            tokens = np.zeros((self.slots, 1), np.int32)
+            for s in active:
+                tokens[s, 0] = self.slot_req[s].output[-1]
+            pend = {"k": self._pend_k, "v": self._pend_v,
+                    "slot": jnp.asarray(self._pend_slots()),
+                    "pos": jnp.asarray(self._pend_pos)}
+            with span("decode.dispatch"):
+                logits, self.pstate, nk, nv = self._plan_decode(
+                    self.params, self.pstate, pend, jnp.asarray(tokens),
+                    jnp.asarray(self.slot_pos))
+                nxt = np.asarray(jnp.argmax(logits, -1))
+            # stream this tick's keys into the session plans: the host
+            # claims each one's Morton-leaf slot now; the device lands it
+            # next tick
+            with span("decode.claim"):
+                phys = self.inserter.insert(
+                    active, nk,
+                    generations={s: self._plan_gen[s] for s in active})
+            self._pend_phys = phys
+            self._pend_k, self._pend_v = nk, nv
+            self._pend_pos = self.slot_pos.copy()
+            for s in active:
+                sess = self._slot_sess[s]
+                sess.phys_hist[int(self.slot_pos[s])] = phys[:, s, :].copy()
+                self.slot_pos[s] += 1
+                self.slot_req[s].output.append(int(nxt[s]))
+            self.store.counters["inserts"] += len(active)
+            self.ticks += 1
         return len(active)
 
     # -- session surgery ----------------------------------------------------
@@ -473,12 +473,8 @@ class ClusterKVEngine(Engine):
             "mode": self.mode, "backend": self.backend,
             "slots": self.slots, "max_seq": self.max_seq,
             "ticks": self.ticks, "tokens_out": self.tokens_out,
-            "tokens_per_sec": (self.tokens_out / self._tick_time
-                               if self._tick_time else 0.0),
             "decode_traces": self.decode_traces,
             "prefill_traces": len(self._prefills) + len(self._pf_plan),
-            "host_claim_s": self._claim_time,
-            "device_tick_s": self._device_time,
         }
         if self.mode == "plan":
             rep.update(self.store.report())

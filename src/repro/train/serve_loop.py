@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import model_api
 from repro.models.sharding import NO_SHARD
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -125,18 +126,22 @@ class Engine:
             req = self.queue.popleft()
             plen = len(req.tokens)
             blen = -(-plen // self.bucket) * self.bucket
-            padded = np.zeros(blen, np.int32)
-            padded[-plen:] = req.tokens          # left-pad into the bucket
-            pf = self._prefill_fn(blen)
-            cache_1, logits = pf(self.params, jnp.asarray(padded[None]))
-            override = self._install(s, req, cache_1, blen)
-            if override is not None:
-                logits = override
-            self.slot_pos[s] = blen
-            tok = int(jnp.argmax(logits[0]))
-            req.output.append(tok)
-            req.t_first = time.time()
-            self.slot_req[s] = req
+            with span("admit", rid=req.rid, blen=blen):
+                padded = np.zeros(blen, np.int32)
+                padded[-plen:] = req.tokens      # left-pad into the bucket
+                with span("admit.prefill"):
+                    pf = self._prefill_fn(blen)
+                    cache_1, logits = jax.block_until_ready(
+                        pf(self.params, jnp.asarray(padded[None])))
+                override = self._install(s, req, cache_1, blen)
+                if override is not None:
+                    logits = override
+                self.slot_pos[s] = blen
+                with span("admit.first_token"):
+                    tok = int(jnp.argmax(logits[0]))
+                req.output.append(tok)
+                req.t_first = time.time()
+                self.slot_req[s] = req
 
     def _retire(self) -> None:
         for s, req in enumerate(self.slot_req):
