@@ -407,9 +407,9 @@ def main(argv=None) -> int:
     # data's 64 Gaussian clusters spread each row block's neighbours over
     # its whole cluster, so the tiles grow faster than n: max_nbr 146 at
     # n = 2^17 (2.4 GB of tiles), 237 at 2^18 (7.95 GB). The Pallas SpMV
-    # reads a lane-packed copy of the tiles, which at 2^18 no longer fits
-    # beside them in the v5e's 16 GB (RESOURCE_EXHAUSTED reserving 7.66
-    # GB). 2^17 is the largest power of two that runs.
+    # reads a copy of the tiles laid out as row-block panels, which at
+    # 2^18 does not fit beside them in the v5e's 16 GB. 2^17 is the
+    # largest power of two that runs.
     n = 1 << 17
     if args.four_chips:
         if len(devices) != 4:

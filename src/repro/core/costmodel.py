@@ -543,39 +543,42 @@ def choose_tiles(shape_key: Tuple[int, ...], f: int = 1,
     """Batch-grid tile sizes ``(rbs, chunk, fc)`` under the VMEM knob.
 
     ``rbs`` row blocks ride one grid step (amortizing grid overhead),
-    ``chunk`` ELL slots are contracted per step, and charges are tiled to
-    ``fc`` feature columns, a multiple of 128 lanes. VMEM per step is
-    counted with the (8, 128) padding: the double-buffered value tiles
-    (lane-dense ``(bs*bs/W, W)``, ``W = max(bs*Q, 128)``), the gathered
-    ``(bs, fc)`` charge segments and the double-buffered ``(bs, fc)``
-    output tiles. Half the VMEM knob is the budget (headroom for the
-    compiler's own scratch). ``chunk`` splits the ELL width as evenly as
-    the budget allows, so VMEM does not grow with it.
+    ``chunk`` ELL slots are contracted per step as one ``(bs, chunk*bs) @
+    (chunk*bs, fc)`` panel matmul per row block, and charges are tiled to
+    ``fc`` feature columns, a multiple of 128 lanes. ``chunk`` obeys the
+    kernel's lane rule (``kernels.bsr_spmv.panel_chunk``): the whole ELL
+    width when it fits, which needs no padding, else the width split as
+    evenly as the budget allows into chunks of whole 128-lane panel
+    columns. VMEM per step is counted with the (8, 128) padding: the
+    double-buffered ``(bs, chunk*bs)`` panels, the gathered
+    ``(chunk*bs, fc)`` charge segments and the double-buffered ``(bs,
+    fc)`` output tiles of each row block, plus one more copy of one row
+    block's dot operands (the float32 ``HIGHEST`` dot's scratch). Half
+    the VMEM knob is the budget (headroom for the compiler's own
+    scratch).
     """
+    from repro.kernels.bsr_spmv import panel_chunk  # imports Pallas
+
     capacity, bs, sb, n_rb, n_cb, max_nbr = shape_key
     hw = hw or get_hardware()
     budget = hw.vmem_bytes / 2
     nbr = max(int(max_nbr or 1), 1)
-    q = max(1, 128 // bs)
-    tile_b = 2 * _vmem_bytes(max(bs // q, 1), bs * q)
     fc = -(-max(int(f), 1) // 128) * 128
 
-    def per_slot(fc_: int) -> float:
-        return tile_b + _vmem_bytes(bs, fc_)
+    def fits(rbs: int, chunk: int, fc_: int) -> bool:
+        panel = _vmem_bytes(bs, chunk * bs)
+        segs = _vmem_bytes(chunk * bs, fc_)
+        per_block = 2 * panel + segs + 2 * _vmem_bytes(bs, fc_)
+        return rbs * per_block + panel + segs <= budget
 
-    def out_b(fc_: int) -> float:
-        return 2 * _vmem_bytes(bs, fc_)
-
-    while fc > 128 and per_slot(fc) + out_b(fc) > budget:
+    smallest = panel_chunk(nbr, bs, 1)
+    while fc > 128 and not fits(1, smallest, fc):
         fc = max(128, (fc // 2) // 128 * 128)
-    cap = max(int((budget - out_b(fc)) // per_slot(fc)), 1)
-    n_ch = -(-nbr // cap)
-    chunk = -(-nbr // n_ch)
-
-    def fits(r: int) -> bool:
-        return r * (chunk * per_slot(fc) + out_b(fc)) <= budget
-
+    n_ch, chunk = 1, panel_chunk(nbr, bs)
+    while chunk > smallest and not fits(1, chunk, fc):
+        n_ch += 1
+        chunk = panel_chunk(nbr, bs, -(-nbr // n_ch))
     rbs = 1
-    while rbs * 2 <= min(max(n_rb, 1), 8) and fits(rbs * 2):
+    while rbs * 2 <= min(max(n_rb, 1), 8) and fits(rbs * 2, chunk, fc):
         rbs *= 2
     return rbs, chunk, fc

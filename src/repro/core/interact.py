@@ -12,9 +12,9 @@ calling them directly:
   spmv_bsr_ml   multi-level path: lax.scan over row-superblocks so the
                 working set per step is a superblock stripe (the TPU analog
                 of the paper's multi-level cache blocking)
-  spmv_pallas   Pallas kernel (kernels/bsr_spmv.py) — MXU tiles with
-                scalar-prefetch column indices; registered as ``pallas``
-                by kernels/ops.py
+  spmv_pallas   Pallas kernel (kernels/bsr_spmv.py) — one MXU panel
+                matmul per row block over DMA-gathered charge segments;
+                registered as ``pallas`` by kernels/ops.py
 
 Iterative-application value updates (t-SNE attractive force, mean shift) are
 computed *blockwise dense* from the current coordinates — the TPU-native

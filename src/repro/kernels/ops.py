@@ -10,6 +10,8 @@ the analytic cost model's hardware config (``core.costmodel
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -36,9 +38,8 @@ def _pallas_backend(plan, x: jax.Array, **_kw) -> jax.Array:
     at B=1). Handles (n,) and (n, f) charges and capacity-padded plans —
     dead-slot rows carry zero tiles and stay zero in the output."""
     b = plan.bsr
-    y = bsr_spmv_batched(b.vals[None], b.col_idx[None], x[None],
-                         shape_key=plan.spec.shape_key)[0]
-    return y[:plan.n]
+    return bsr_spmv(b.vals, b.col_idx, x, plan.n,
+                    shape_key=plan.spec.shape_key)
 
 
 _pallas_backend.interpret_only = _interpret
@@ -55,11 +56,16 @@ def _pallas_batched(spec, data, xs: jax.Array) -> jax.Array:
 _pallas_batched.interpret_only = _interpret
 
 
+@functools.partial(jax.jit, static_argnames=("n", "shape_key"))
 def bsr_spmv(vals: jax.Array, col_idx: jax.Array, x: jax.Array,
-             n: int | None = None) -> jax.Array:
+             n: int | None = None,
+             shape_key: tuple | None = None) -> jax.Array:
     """ELL-BSR SpMV/SpMM of one matrix (the batch-grid kernel at B=1).
-    x (n,) or (n, f); returns the same leading length (``n`` if given)."""
-    y = bsr_spmv_batched(vals[None], col_idx[None], x[None])[0]
+    x (n,) or (n, f); returns the same leading length (``n`` if given).
+    One program: the batch axis costs nothing here, where an eager
+    ``vals[None]`` would copy every tile."""
+    y = bsr_spmv_batched(vals[None], col_idx[None], x[None],
+                         shape_key=shape_key)[0]
     return y if n is None else y[:n]
 
 

@@ -112,34 +112,61 @@ def test_exchange_cost_monotone_and_none_passthrough():
 
 
 def test_choose_tiles_contracts():
-    key = (512, 16, 8, 32, 32, 6)
+    key = (512, 16, 8, 32, 32, 24)
     rbs, chunk, fc = costmodel.choose_tiles(key, f=4)
-    assert chunk == 6          # the whole ELL width fits the budget
+    assert chunk == 24         # the whole ELL width fits the budget
     assert fc == 128           # feature tiles are whole 128-lane tiles
     assert rbs in (1, 2, 4, 8) and rbs <= 8
     # a starved VMEM budget splits the slot sum and shrinks the superblock;
     # the feature tile never drops below one lane tile
     tiny = HardwareConfig(vmem_bytes=64 * 1024)
     rbs_t, chunk_t, fc_t = costmodel.choose_tiles(key, f=16, hw=tiny)
-    assert chunk_t < 6
+    assert chunk_t < 24
     assert fc_t == 128
     assert rbs_t <= rbs
 
 
+def _panel_vmem(bs, rbs, chunk, fc):
+    """What choose_tiles promises to hold per grid step: per row block the
+    double-buffered panel, the gathered segments and the double-buffered
+    output tile, plus one row block's dot operands once more."""
+    pad = costmodel._vmem_bytes
+    panel, segs = pad(bs, chunk * bs), pad(chunk * bs, fc)
+    return rbs * (2 * panel + segs + 2 * pad(bs, fc)) + panel + segs
+
+
 def test_choose_tiles_counts_lane_padding():
     """A (16, 4) charge segment occupies a (16, 128) VMEM tile: the budget
-    holds what the padded tiles take, not the nominal bytes."""
-    hw = HardwareConfig(vmem_bytes=256 * 1024)
+    holds what the padded panels and segments take, not the nominal
+    bytes."""
+    hw = HardwareConfig(vmem_bytes=1024 * 1024)
     bs, nbr = 16, 64
     key = (1024, bs, 8, 64, 64, nbr)
     rbs, chunk, fc = costmodel.choose_tiles(key, f=4, hw=hw)
     pad = costmodel._vmem_bytes
-    q = 128 // bs
-    per_slot = 2 * pad(bs // q, bs * q) + pad(bs, fc)
-    used = rbs * (chunk * per_slot + 2 * pad(bs, fc))
     assert pad(bs, 4) == pad(bs, 128) == bs * 128 * 4
-    assert used <= hw.vmem_bytes / 2
+    assert _panel_vmem(bs, rbs, chunk, fc) <= hw.vmem_bytes / 2
+    assert chunk * bs % 128 == 0
     assert chunk < nbr         # nominal bytes would have kept every slot
+
+
+@pytest.mark.parametrize("key,f", [
+    ((131072, 32, 8, 4096, 4096, 158), 128),   # the interact-f128 cell
+    ((131072, 32, 8, 4096, 4096, 158), 1),
+    ((1 << 20, 32, 8, 1 << 15, 1 << 15, 16), 128),
+    ((1 << 18, 32, 8, 1 << 13, 1 << 13, 160), 300),
+    ((1024, 16, 8, 64, 64, 20), 4),
+    ((128, 16, 4, 8, 8, 4), 1),                # admission-sized: whole-dim
+    ((4096, 64, 8, 64, 64, 1000), 128),        # wide rows: chunks split
+])
+def test_choose_tiles_meets_lane_rule_within_budget(key, f):
+    """Every chunk is the whole ELL width or whole 128-lane panel
+    columns, and the panel form's VMEM stays within half the knob."""
+    bs, nbr = key[1], key[5]
+    rbs, chunk, fc = costmodel.choose_tiles(key, f=f)
+    assert chunk == nbr or chunk * bs % 128 == 0
+    assert chunk <= nbr
+    assert _panel_vmem(bs, rbs, chunk, fc) <= HardwareConfig().vmem_bytes / 2
 
 
 def test_get_hardware_by_device_kind(monkeypatch):

@@ -10,6 +10,7 @@ from repro.core.interact import spmv_bsr_ml_batched
 from repro.kernels import ops, ref
 from repro.kernels.block_attention import block_attention as ba_kernel
 from repro.kernels.bsr_spmv import bsr_spmv_batched as batch_kernel
+from repro.kernels.bsr_spmv import panel_chunk
 from repro.kernels.gamma_score import gamma_pairs
 
 
@@ -99,8 +100,8 @@ def test_gamma_pairs_shapes(nnz, bn):
 
 # -- batch-grid kernel: edge shapes, all matching bsr_ml batched ------------
 #
-# The kernel splits the ELL slot sum into chunks and contracts each tile on
-# the MXU, where the XLA ``bsr_ml`` path sums every slot in one
+# The kernel contracts a row block's chunk of ELL slots as one panel matmul
+# on the MXU, where the XLA ``bsr_ml`` path sums every slot in one
 # batch_matmul: the same products, associated differently. float32 rounds
 # each add at ~6e-8 relative, so with a handful of O(1) slots the two agree
 # to ~1e-6; 1e-5 is the bound (bitwise parity would forbid the chip's form).
@@ -117,25 +118,48 @@ def _random_batch(B, n_cb, bs, nbr, seed=0):
             jnp.asarray(np.stack(idxs), jnp.int32))
 
 
-@pytest.mark.parametrize("B,n_cb,bs,nbr,f,rbs,fct", [
-    (1, 8, 16, 4, 1, 1, None),     # degenerate single member
-    (3, 8, 16, 4, 1, 4, None),     # row-superblocked, scalar charges
-    (3, 8, 16, 4, 3, 2, 2),        # f not a multiple of the feature tile
-    (2, 8, 16, 4, 5, 3, 4),        # rbs not dividing n_rb (row padding)
-    (2, 8, 32, 5, 300, 2, 1),      # three 128-lane feature tiles
+@pytest.mark.parametrize("B,n_cb,bs,nbr,f,rbs,fct,chunk", [
+    (1, 8, 16, 4, 1, 1, None, 3),     # degenerate single member
+    (3, 8, 16, 4, 1, 4, None, 3),     # row-superblocked, scalar charges
+    (3, 8, 16, 4, 3, 2, 2, 3),        # f not a multiple of the feature tile
+    (2, 8, 16, 4, 5, 3, 4, 3),        # rbs not dividing n_rb (row padding)
+    (2, 8, 32, 5, 300, 2, 1, 3),      # three 128-lane feature tiles
+    (2, 16, 32, 10, 4, 2, None, 3),   # 3*32 lanes: chunk 3 -> 4, nbr 10 -> 12
+    (1, 32, 16, 20, 2, 1, None, 5),   # 5*16 lanes: chunk 5 -> 8, nbr 20 -> 24
+    (2, 8, 16, 4, 3, 2, None, None),  # whole-dim panel, 4*16 < 128 lanes
+    (2, 8, 32, 3, 1, 1, None, None),  # whole-dim panel, 3*32 < 128 lanes
+    (1, 8, 64, 6, 5, 2, None, 1),     # bs 64: chunk 1 -> 2, three chunks
+    (2, 8, 16, 4, 257, 1, 1, None),   # whole-dim panel, three f tiles
+    (1, 4, 64, 1, 1, 2, None, None),  # bs 64, one slot, f = 1
 ])
-def test_batch_kernel_bit_matches_bsr_ml(B, n_cb, bs, nbr, f, rbs, fct):
-    """``fct`` counts 128-lane feature tiles (None: the default tile)."""
+def test_batch_kernel_matches_bsr_ml(B, n_cb, bs, nbr, f, rbs, fct, chunk):
+    """``fct`` counts 128-lane feature tiles (None: the default tile);
+    ``chunk`` is the requested slot chunk, which the lane rule rounds."""
     vals, col_idx = _random_batch(B, n_cb, bs, nbr)
     rng = np.random.default_rng(9)
     shape = (B, n_cb * bs) if f == 1 else (B, n_cb * bs, f)
     xs = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     fc = 128 * (fct or 1)
-    got = batch_kernel(vals, col_idx, xs, rbs=rbs, chunk=3, fc=fc,
+    got = batch_kernel(vals, col_idx, xs, rbs=rbs, chunk=chunk, fc=fc,
                        interpret=True)
     want = spmv_bsr_ml_batched(vals, col_idx, xs, 8)
     assert got.dtype == want.dtype and got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
+
+
+@pytest.mark.parametrize("nbr,bs,chunk,want", [
+    (158, 32, None, 158),   # the whole ELL width: a whole-dim block
+    (158, 32, 80, 80),      # 80 * 32 lanes: already whole lane tiles
+    (158, 32, 3, 4),        # rounded up to one 128-lane column
+    (4, 16, 3, 4),          # 8 slots would pass the width: whole-dim
+    (20, 16, 5, 8),
+    (6, 64, 1, 2),
+    (3, 128, 1, 1),
+])
+def test_panel_chunk_lane_rule(nbr, bs, chunk, want):
+    got = panel_chunk(nbr, bs, chunk)
+    assert got == want
+    assert got == nbr or got * bs % 128 == 0
 
 
 def _holey_batch():

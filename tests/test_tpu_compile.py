@@ -47,6 +47,7 @@ def _has_kernel(compiled) -> bool:
     (1 << 20, 16, 128),        # a 128-charge block
     (1 << 18, 160, 1),         # the chip smoke's plan: wide ELL rows
     (1 << 18, 160, 128),
+    (1 << 17, 158, 128),       # the interact-f128 cell's plan
 ])
 def test_bsr_spmv_batched_compiles(one_chip, n, nbr, f):
     bs = 32
@@ -63,6 +64,42 @@ def test_bsr_spmv_batched_compiles(one_chip, n, nbr, f):
         _spec((1, n_rb, nbr), jnp.int32, one_chip),
         _spec(xshape, jnp.float32, one_chip)).compile()
     assert _has_kernel(compiled)
+
+
+def test_single_plan_spmv_copies_tiles_twice(one_chip, monkeypatch):
+    """The pallas backend's program at the interact-f128 cell's plan
+    makes two full copies of the tiles on their way to the panel layout
+    (a swap of two major dims, then the transpose), and no third (a pad
+    of the tiles before the transpose would be one)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n, bs, nbr = 1 << 17, 32, 158
+    n_rb = n // bs
+
+    def run(v, i, x):
+        return ops.bsr_spmv(v, i, x, n, shape_key=(n, bs, 8, n_rb, n_rb,
+                                                   nbr))
+
+    compiled = jax.jit(run).lower(
+        _spec((n_rb, nbr, bs, bs), jnp.float32, one_chip),
+        _spec((n_rb, nbr), jnp.int32, one_chip),
+        _spec((n, 128), jnp.float32, one_chip)).compile()
+    tiles = n_rb * nbr * bs * bs
+    made = [line.strip() for line in compiled.as_text().splitlines()
+            if _writes_f32(line) >= tiles and "parameter(" not in line
+            and " bitcast(" not in line]
+    assert _has_kernel(compiled)
+    assert len(made) == 2, made
+
+
+def _writes_f32(line: str) -> int:
+    """Elements of the float32 array an HLO instruction line defines."""
+    if " = f32[" not in line:
+        return 0
+    out = 1
+    for d in line.split(" = f32[", 1)[1].split("]", 1)[0].split(","):
+        out *= int(d)
+    return out
 
 
 @pytest.mark.parametrize("dtype,n_sel,has_self", [
