@@ -6,6 +6,7 @@ from repro.configs.base import (
     ModelConfig,
     MoEConfig,
     SSMConfig,
+    YarnConfig,
     all_cells,
     cells,
     get_config,
@@ -20,6 +21,7 @@ __all__ = [
     "ModelConfig",
     "MoEConfig",
     "SSMConfig",
+    "YarnConfig",
     "all_cells",
     "cells",
     "get_config",

@@ -27,13 +27,24 @@ SHAPES: Dict[str, Tuple[int, int, str]] = {
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 0
+    n_experts: int = 0                 # routed experts: the router's outputs
     top_k: int = 1
     d_ff_expert: int = 0
-    n_shared_experts: int = 0          # shared (always-on) experts
+    n_shared_experts: int = 0          # shared (always-on) experts, run as
+                                       # one MLP of n_shared * d_ff_expert
     router_jitter: float = 0.0
     expert_parallel: bool = False      # EP all-to-all instead of expert-dim TP
-    capacity_factor: float = 1.25      # tokens/expert cap multiplier
+    capacity_factor: float = 1.25      # tokens/expert cap multiplier (the
+                                       # training path; serving is dropless)
+    norm_topk_prob: bool = True        # renormalise the top-k gates to sum 1
+    experts_held: int = 0              # routed experts this chip holds
+                                       # (0 = all): the chip's share under
+                                       # expert parallelism
+    first_held: int = 0                # index of the first held expert
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -48,12 +59,30 @@ class SSMConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it:
+    ``inv_freq`` mixes the base frequencies and the ones slowed by
+    ``factor`` along a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position``; the softmax scale gains
+    ``(0.1 * mscale_all_dim * ln(factor) + 1)^2``. The published
+    ``mscale`` equals ``mscale_all_dim``, which makes the cos/sin scale 1."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 768
+    q_lora_rank: int = 768             # 0: a direct q projection (no LoRA)
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+    yarn: Optional[YarnConfig] = None  # YaRN on the rope dims; None = plain
+
 
 
 @dataclass(frozen=True)
@@ -92,6 +121,9 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     mla: Optional[MLAConfig] = None
+    # leading layers whose FFN is a dense MLP of d_ff (DeepSeek's
+    # first_k_dense_replace); the rest take the MoE FFN
+    first_k_dense: int = 0
     # hybrid (zamba2-like): shared attention block every `shared_attn_every`
     shared_attn_every: int = 0
     # enc-dec (whisper-like)

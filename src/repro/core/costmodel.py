@@ -411,7 +411,9 @@ class DecodeFeatures:
     ``s`` is the plan capacity (padded cache length), ``bk`` the tile
     edge, ``n_sel`` the top-c tiles attended per head. The work is
     identical across backends — what differs is how often the selected
-    tiles cross HBM and how many launches a tick pays."""
+    tiles cross HBM and how many launches a tick pays. ``shared_kv``:
+    the values are the leading ``dv`` columns of the key rows (MLA's
+    latent), one array, which only the ``xla`` path reads."""
     batch: int
     hq: int
     hkv: int
@@ -420,6 +422,7 @@ class DecodeFeatures:
     dv: int
     bk: int
     n_sel: int
+    shared_kv: bool = False
 
 
 def decode_cost(feat: DecodeFeatures, backend: str,
@@ -478,6 +481,8 @@ def rank_decode_backends(feat: DecodeFeatures,
     costs: Dict[str, dict] = {}
     predicted: Dict[str, float] = {}
     for name in names:
+        if feat.shared_kv and name == "pallas":
+            continue        # the fused kernel takes k and v as two arrays
         c = decode_cost(feat, name, hw, interpret=interpret)
         costs[name] = c
         predicted[name] = c["seconds"]

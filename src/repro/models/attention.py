@@ -34,11 +34,15 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jax.Array, pos: jax.Array, theta: float = 1e4) -> jax.Array:
-    """Rotary embedding. x (..., S, dh), pos (..., S) broadcastable."""
+def rope(x: jax.Array, pos: jax.Array, theta: float = 1e4,
+         inv_freq=None) -> jax.Array:
+    """Rotary embedding on the two halves of the last dim. x (..., S, dh),
+    pos (..., S) broadcastable. ``inv_freq`` (dh/2,) replaces the plain
+    ``theta`` frequencies (YaRN's mixed ones, ``models.mla``)."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     ang = pos[..., None].astype(jnp.float32) * freqs      # (..., S, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]
@@ -268,11 +272,17 @@ def clusterkv_decode(q, k, v, kpos, qpos, cfg: ClusterKVConfig):
 
 
 def clusterkv_plan_decode(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
-                          k_self=None, v_self=None):
+                          k_self=None, v_self=None, dv=None, layer=None):
     """Single-token decode over PLAN-ordered caches (the decode service).
 
     q (B,Hq,dh); ks/vs (B,Hkv,S,dh) keys/values already in plan (cluster)
-    order; ps (B,Hkv,S) int32 original time position of each plan slot,
+    order. ``vs=None`` means the values are the leading ``dv`` columns of
+    the key rows themselves (MLA's latent rows ``[c || k_rope]``, whose
+    values are ``c``): one array, never a second copy, and ``v_self`` is
+    likewise read off ``k_self``; with ``layer`` (values shared only),
+    ``ks`` is the (L,B,Hkv,S,dh) stack of every layer and layer ``layer``
+    is gathered from it in place (a slice first would copy the layer);
+    ps (B,Hkv,S) int32 original time position of each plan slot,
     with ``INT32_MAX`` marking capacity holes not yet streamed into;
     cent (B,Hkv,S/bk,dh) per-tile centroids maintained incrementally by
     the service; qpos (B,) per-slot decode positions.
@@ -292,7 +302,9 @@ def clusterkv_plan_decode(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
     ``repro.cost/v1`` model that ranks the SpMV backends — which prices
     the fused kernel's single launch and once-only tile traffic against
     the XLA path's gather round-trip (and its interpret-mode slowdown on
-    CPU, where the XLA path keeps winning).
+    CPU, where the XLA path keeps winning). The fused kernel reads keys
+    and values from two arrays, so values shared with the keys (``vs`` is
+    None) rank only the backends that read them from one.
     """
     from repro.core.registry import get_decode_backend
 
@@ -302,21 +314,32 @@ def clusterkv_plan_decode(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
         from repro.kernels import ops as kops
 
         b, hq, dh = q.shape
-        hkv, s = ks.shape[1], ks.shape[2]
+        hkv, s = ps.shape[1], ps.shape[2]
         bk = min(cfg.block_k, s)
         feat = costmodel.DecodeFeatures(
-            batch=b, hq=hq, hkv=hkv, s=s, dh=dh, dv=vs.shape[-1], bk=bk,
-            n_sel=min(cfg.decode_clusters, s // bk))
+            batch=b, hq=hq, hkv=hkv, s=s, dh=dh,
+            dv=dv if vs is None else vs.shape[-1], bk=bk,
+            n_sel=min(cfg.decode_clusters, s // bk),
+            shared_kv=vs is None)
         name = costmodel.choose_decode_backend(
             feat, interpret=kops._interpret())
+    if vs is None:
+        kw = {"dv": dv, "layer": layer}
+    else:
+        kw = {}
     return get_decode_backend(name)(q, ks, vs, ps, cent, qpos, cfg,
-                                    k_self=k_self, v_self=v_self)
+                                    k_self=k_self, v_self=v_self, **kw)
 
 
 @register_decode_backend("xla")
 def _plan_decode_xla(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
-                     k_self=None, v_self=None):
-    """The unfused reference: top-k select, vmapped tile gather, attend."""
+                     k_self=None, v_self=None, dv=None, layer=None):
+    """The unfused reference: top-k select, vmapped tile gather, attend.
+    With ``vs`` None the values are the gathered key rows' first ``dv``
+    columns (see :func:`clusterkv_plan_decode`)."""
+    if vs is None:
+        return _plan_decode_xla_shared(q, ks, ps, cent, qpos, cfg,
+                                       k_self=k_self, dv=dv, layer=layer)
     b, hq, dh = q.shape
     hkv, s = ks.shape[1], ks.shape[2]
     g = hq // hkv
@@ -357,6 +380,47 @@ def _plan_decode_xla(q, ks, vs, ps, cent, qpos, cfg: ClusterKVConfig, *,
         q.reshape(b, hkv, g, dh), kb, vb, pt, idx,
         k_self, v_self, self_pos, qp)
     return out.reshape(b, hq, dv)
+
+
+def _plan_decode_xla_shared(q, ks, ps, cent, qpos, cfg: ClusterKVConfig, *,
+                            k_self=None, dv, layer=None):
+    """:func:`_plan_decode_xla` for values that are the leading ``dv``
+    columns of the key rows. Each selected row is gathered once (from the
+    layer stack in place when ``layer`` is given) and both products run
+    on the rows' own dtype with float32 accumulation; the token's own row
+    joins the softmax as one more column."""
+    b, hq, dh = q.shape
+    hkv, s = ps.shape[1], ps.shape[2]
+    g = hq // hkv
+    bk = min(cfg.block_k, s)
+    n_sel = min(cfg.decode_clusters, s // bk)
+    qp = qpos.astype(jnp.int32)
+    idx = ckv.plan_decode_select(q, ps, cent, qp, n_sel, bk,
+                                 cfg.local_window_blocks * bk)
+    rows = (idx[..., None] * bk + jnp.arange(bk)).reshape(b, hkv, -1)
+    bi = jnp.arange(b)[:, None, None]
+    hi = jnp.arange(hkv)[None, :, None]
+    sel = ks[bi, hi, rows] if layer is None else ks[layer, bi, hi, rows]
+    live = jnp.take_along_axis(ps, rows, axis=2) <= qp[:, None, None]
+    qg = q.reshape(b, hkv, g, dh).astype(sel.dtype)
+    scale = jnp.sqrt(jnp.asarray(dh, jnp.float32))
+    logit = jnp.einsum("bhgw,bhnw->bhgn", qg, sel,
+                       preferred_element_type=jnp.float32) / scale
+    mask = jnp.broadcast_to(live[:, :, None, :], logit.shape)
+    if k_self is not None:
+        own = k_self.astype(sel.dtype)                    # (B,Hkv,dh)
+        logit = jnp.concatenate([logit, jnp.einsum(
+            "bhgw,bhw->bhg", qg, own,
+            preferred_element_type=jnp.float32)[..., None] / scale], -1)
+        mask = jnp.concatenate([mask, jnp.ones(logit.shape[:-1] + (1,),
+                                               bool)], -1)
+    w = ckv.masked_softmax(logit, mask)
+    out = jnp.einsum("bhgn,bhnw->bhgw", w[..., :sel.shape[2]]
+                     .astype(sel.dtype), sel,
+                     preferred_element_type=jnp.float32)
+    if k_self is not None:
+        out = out + w[..., -1:] * own.astype(jnp.float32)[:, :, None, :]
+    return out[..., :dv].reshape(b, hq, dv).astype(q.dtype)
 
 
 def clusterkv_percall_decode(q, k, v, kpos, qpos, cfg: ClusterKVConfig):

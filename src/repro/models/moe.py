@@ -7,9 +7,15 @@ granite's 40 experts on a 16-way axis), with the row-parallel down-proj
 combined by an explicit psum. Optional EP (experts over the tensor axis with
 all-to-all token exchange) is provided for divisible counts.
 
-Dropped-token semantics: tokens beyond an expert's capacity
-(ceil(T*k/E * capacity_factor)) are dropped (Switch-style); the residual
-stream carries them unchanged.
+Dropped-token semantics (training, :func:`moe_ffn`): tokens beyond an
+expert's capacity (ceil(T*k/E * capacity_factor)) are dropped
+(Switch-style); the residual stream carries them unchanged.
+
+Serving (:func:`moe_serve`) is dropless and told which experts this chip
+holds (``MoEConfig.experts_held`` from ``first_held``): it routes over all
+``n_experts``, computes the held experts' part of the result for the
+tokens routed to them, and leaves out what experts held elsewhere would
+add (expert parallelism without its exchange).
 """
 from __future__ import annotations
 
@@ -33,6 +39,7 @@ CAPACITY_FACTOR = 1.25
 def init_moe(key, cfg: ModelConfig):
     m = cfg.moe
     d, e, f = cfg.d_model, m.n_experts, m.d_ff_expert
+    eh = m.held                      # expert weights: only the held share
     ks = jax.random.split(key, 6)
     scale_in = 1.0 / math.sqrt(d)
     scale_out = 1.0 / math.sqrt(f)
@@ -40,9 +47,9 @@ def init_moe(key, cfg: ModelConfig):
     tp_in = None if m.expert_parallel else "tp"
     p = {
         "router": {"w": jax.random.normal(ks[0], (d, e), jnp.float32) * scale_in},
-        "wg": jax.random.normal(ks[1], (e, d, f), jnp.float32) * scale_in,
-        "wu": jax.random.normal(ks[2], (e, d, f), jnp.float32) * scale_in,
-        "wd": jax.random.normal(ks[3], (e, f, d), jnp.float32) * scale_out,
+        "wg": jax.random.normal(ks[1], (eh, d, f), jnp.float32) * scale_in,
+        "wu": jax.random.normal(ks[2], (eh, d, f), jnp.float32) * scale_in,
+        "wd": jax.random.normal(ks[3], (eh, f, d), jnp.float32) * scale_out,
     }
     s = {
         "router": {"w": P("fsdp", None)},
@@ -141,16 +148,96 @@ def load_balance_loss(probs, eidx, n_experts: int):
     return n_experts * jnp.sum(f * pbar)
 
 
+def _route(p, x: jax.Array, m):
+    """Softmax router over all ``n_experts``, greedy top-k. The gates are
+    the top-k probabilities, renormalised to sum 1 where the model says
+    so (``norm_topk_prob``)."""
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ p["router"]["w"], axis=-1)
+    gates, eidx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return probs, gates, eidx
+
+
+def _shared(p, x):
+    sh = p["shared"]
+    h = jax.nn.silu(x @ sh["wg"].astype(x.dtype)) * (x @ sh["wu"].astype(x.dtype))
+    return h @ sh["wd"].astype(x.dtype)
+
+
+# serving-side counters of one moe_serve call (int32, summed over tokens)
+MOE_STATS = ("moe_experts_read", "moe_pairs_local", "moe_pairs_total",
+             "moe_pairs_dropped")
+
+
+def moe_serve(p, x: jax.Array, cfg: ModelConfig, active=None
+              ) -> Tuple[jax.Array, jax.Array]:
+    """The serving MoE FFN: dropless, over this chip's held experts.
+
+    x (..., d); ``active`` (...) bool marks the tokens the counters count
+    (None: every token). Every token is routed over all ``n_experts``;
+    each (token, expert) pair whose expert is held here is dispatched to
+    it (sorted by expert, capacity = the token count, so no expert can
+    overflow and no pair is dropped) and combined with its gate; pairs
+    for experts held elsewhere are left out. Shared experts run once, on
+    every token.
+
+    Returns ``(y, stats)`` with ``stats`` int32 in ``MOE_STATS`` order:
+    distinct held experts the active tokens reached, their pairs routed
+    here, all their pairs, and the pairs routed here that no expert
+    computed (0 unless the dispatch is wrong)."""
+    m = cfg.moe
+    shape = x.shape
+    d = shape[-1]
+    xf = x.reshape(-1, d)
+    t, k, eh = xf.shape[0], m.top_k, m.held
+    _, gates, eidx = _route(p, xf, m)
+    le = eidx.reshape(-1) - m.first_held               # held-local index
+    local = (le >= 0) & (le < eh)
+    le = jnp.where(local, le, eh)                      # elsewhere: sorts last
+    order = jnp.argsort(le, stable=True)
+    sorted_e = le[order]
+    counts = jnp.bincount(le, length=eh + 1)
+    starts = jnp.cumsum(counts) - counts
+    rank = jnp.arange(t * k) - starts[sorted_e]
+    cap = t
+    keep = (sorted_e < eh) & (rank < cap)
+    dest = jnp.where(keep, sorted_e * cap + rank, eh * cap)   # eh*cap: drop
+    token_of = order // k
+    buf = jnp.zeros((eh * cap, d), x.dtype).at[dest].set(
+        xf[token_of], mode="drop").reshape(eh, cap, d)
+    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["wg"].astype(x.dtype)))
+    h = h * jnp.einsum("ecd,edf->ecf", buf, p["wu"].astype(x.dtype))
+    out = jnp.einsum("ecf,efd->ecd", h, p["wd"].astype(x.dtype))
+    out = out.reshape(eh * cap, d)
+    g = (gates.reshape(-1)[order] * keep).astype(x.dtype)[:, None]
+    y = jnp.zeros_like(xf).at[token_of].add(
+        out[jnp.minimum(dest, eh * cap - 1)] * g)
+    if "shared" in p:
+        y = y + _shared(p, xf)
+
+    act = (jnp.ones((t,), bool) if active is None
+           else jnp.broadcast_to(active, shape[:-1]).reshape(t))
+    pair_act = act[token_of]
+    loc = pair_act & (sorted_e < eh)
+    reached = jnp.zeros((eh + 1,), jnp.int32).at[sorted_e].max(
+        loc.astype(jnp.int32))[:eh]
+    i32 = lambda v: jnp.sum(v, dtype=jnp.int32)
+    stats = jnp.stack([i32(reached), i32(loc), i32(pair_act),
+                       i32(loc & ~keep)])
+    return y.reshape(shape), stats
+
+
 def moe_ffn(p, x: jax.Array, cfg: ModelConfig, shd: ShardCtx
             ) -> Tuple[jax.Array, jax.Array]:
     """x (B, S, d) -> (y (B, S, d), aux_loss scalar)."""
     m = cfg.moe
+    if m.held != m.n_experts:
+        raise NotImplementedError(
+            "the training MoE holds every expert; a held share serves only "
+            "(moe_serve)")
     b, s, d = x.shape
-    xf32 = x.astype(jnp.float32)
-    logits = xf32 @ p["router"]["w"]                 # (B,S,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, eidx = jax.lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    probs, gates, eidx = _route(p, x, m)
     aux = load_balance_loss(probs.reshape(-1, m.n_experts).astype(jnp.float32),
                             eidx.reshape(-1, m.top_k), m.n_experts)
 
@@ -219,7 +306,5 @@ def moe_ffn(p, x: jax.Array, cfg: ModelConfig, shd: ShardCtx
         y = f(x, eidx, gates, p["wg"], p["wu"], p["wd"])
 
     if "shared" in p:
-        sh = p["shared"]
-        h = jax.nn.silu(x @ sh["wg"].astype(x.dtype)) * (x @ sh["wu"].astype(x.dtype))
-        y = y + h @ sh["wd"].astype(x.dtype)
+        y = y + _shared(p, x)
     return y, aux

@@ -50,7 +50,7 @@ def _init_mlp(key, cfg: ModelConfig):
     return ({"wg": pg, "wu": pu, "wd": pd}, {"wg": sg, "wu": su, "wd": sd})
 
 
-def _init_layer(key, cfg: ModelConfig):
+def _init_layer(key, cfg: ModelConfig, dense: bool = False):
     ks = jax.random.split(key, 4)
     p, s = {}, {}
     p["ln1"], s["ln1"] = pm.rmsnorm(cfg.d_model)
@@ -59,7 +59,7 @@ def _init_layer(key, cfg: ModelConfig):
         p["attn"], s["attn"] = mla_mod.init_mla(ks[0], cfg)
     else:
         p["attn"], s["attn"] = _init_attn(ks[0], cfg)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["ffn"], s["ffn"] = moe_mod.init_moe(ks[1], cfg)
     else:
         p["ffn"], s["ffn"] = _init_mlp(ks[1], cfg)
@@ -67,12 +67,19 @@ def _init_layer(key, cfg: ModelConfig):
 
 
 def init_lm(cfg: ModelConfig, key) -> Tuple[dict, dict]:
+    """Embedding, the stacked layers, final norm and (untied) head. The
+    ``first_k_dense`` leading layers, whose FFN is a dense MLP, stack apart
+    as ``dense_layers``."""
     ks = jax.random.split(key, 4)
     p, s = {}, {}
     if not cfg.embedding_inputs:
         p["embed"], s["embed"] = pm.embedding(ks[0], cfg.vocab, cfg.d_model)
+    nd = cfg.first_k_dense
+    if nd:
+        p["dense_layers"], s["dense_layers"] = pm.stacked(
+            lambda k: _init_layer(k, cfg, dense=True), nd, ks[3])
     p["layers"], s["layers"] = pm.stacked(
-        lambda k: _init_layer(k, cfg), cfg.n_layers, ks[1])
+        lambda k: _init_layer(k, cfg), cfg.n_layers - nd, ks[1])
     p["ln_f"], s["ln_f"] = pm.rmsnorm(cfg.d_model)
     if not cfg.tie_embeddings:
         p["head"], s["head"] = pm.linear(ks[2], cfg.d_model, cfg.vocab,
@@ -117,7 +124,8 @@ def _apply_mlp(lp, x):
     return pm.apply_linear(lp["wd"], h)
 
 
-def _layer(lp, x, pos, cfg: ModelConfig, shd: ShardCtx, backend: str):
+def _layer(lp, x, pos, cfg: ModelConfig, shd: ShardCtx, backend: str,
+           dense: bool = False):
     b, s, d = x.shape
     h = pm.apply_rmsnorm(lp["ln1"], x, cfg.norm_eps)
     if cfg.mla is not None:
@@ -129,7 +137,7 @@ def _layer(lp, x, pos, cfg: ModelConfig, shd: ShardCtx, backend: str):
                             .reshape(b, s, -1))
     x = shd.cst(x + a, "dp", None, None)
     h = pm.apply_rmsnorm(lp["ln2"], x, cfg.norm_eps)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         f, aux = moe_mod.moe_ffn(lp["ffn"], h, cfg, shd)
     else:
         f, aux = _apply_mlp(lp["ffn"], h), jnp.zeros((), jnp.float32)
@@ -158,14 +166,19 @@ def forward(p, cfg: ModelConfig, batch: Dict[str, jax.Array], shd: ShardCtx,
     s = h.shape[1]
     pos = jnp.arange(s, dtype=jnp.int32)
 
-    def body(carry, lp):
+    def body(dense, carry, lp):
         x, aux = carry
-        x, a = _layer(lp, x, pos, cfg, shd, backend)
+        x, a = _layer(lp, x, pos, cfg, shd, backend, dense)
         return (x, aux + a), None
 
-    body = pm.maybe_remat(body, cfg)
-    (h, aux), _ = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                               p["layers"])
+    carry = (h, jnp.zeros((), jnp.float32))
+    if cfg.first_k_dense:
+        carry, _ = jax.lax.scan(
+            pm.maybe_remat(lambda c, lp: body(True, c, lp), cfg), carry,
+            p["dense_layers"])
+    (h, aux), _ = jax.lax.scan(
+        pm.maybe_remat(lambda c, lp: body(False, c, lp), cfg), carry,
+        p["layers"])
     return pm.apply_rmsnorm(p["ln_f"], h, cfg.norm_eps), aux
 
 
@@ -356,8 +369,11 @@ def plan_prefill(p, cfg: ModelConfig, batch, perms, shd: ShardCtx
     generated token already comes from the clusterkv kernel the service
     decodes with. Returns last-position logits only (the service keeps its
     cache plan-ordered; the time-ordered cache of :func:`prefill` never
-    exists here)."""
-    if cfg.mla is not None or cfg.embedding_inputs:
+    exists here). MLA models prefill through their latent plans
+    (:func:`repro.models.mla.plan_prefill`)."""
+    if cfg.mla is not None:
+        return mla_mod.plan_prefill(p, cfg, batch, perms, shd)
+    if cfg.embedding_inputs:
         raise NotImplementedError(
             "plan prefill serves token decoder-only models")
     h = embed_tokens(p, cfg, batch, shd)
@@ -412,9 +428,13 @@ def plan_decode_step(p, cfg: ModelConfig, pstate, pend, tokens, slot_pos,
     self-attention never waits on the landing). tokens (B,1);
     slot_pos (B,). Returns (logits, new_pstate, k_new, v_new) where
     k_new/v_new (L,B,Hkv,dh) are THIS tick's rows for the host to claim
-    slots for.
+    slots for. MLA models keep one plan per layer over their latent rows
+    (:func:`repro.models.mla.plan_decode_step`).
     """
-    if cfg.mla is not None or cfg.embedding_inputs:
+    if cfg.mla is not None:
+        return mla_mod.plan_decode_step(p, cfg, pstate, pend, tokens,
+                                        slot_pos, shd)
+    if cfg.embedding_inputs:
         raise NotImplementedError(
             "plan decode serves token decoder-only models")
     ckv_cfg = cfg.clusterkv

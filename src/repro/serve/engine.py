@@ -17,6 +17,13 @@ tick (a Morton sort per token); the service instead
     counts retraces at trace time; the service gate is that it stays 1
     across arbitrary admission churn.
 
+An MLA model (``cfg.mla``) keeps ONE plan per layer over its latent rows
+``[c || k_rope]`` (``Hkv = 1``, ``dh`` = kv_lora_rank + rope dims): the
+plan state holds each row once (``ks``, no ``vs``: the values are the
+rows' first kv_lora_rank columns), and its tick
+(``models.mla.plan_decode_step``) keeps device-side counters in
+``pstate["stats"]``, read by :meth:`ClusterKVEngine.counters`.
+
 ``mode="percall"`` runs the same engine over the baseline per-call
 clusterkv decode (``backend="clusterkv"``) for A/B benchmarking.
 """
@@ -33,6 +40,7 @@ import numpy as np
 from repro import api
 from repro.configs.base import ModelConfig
 from repro.core import clusterkv as ckv
+from repro.models import mla as mla_mod
 from repro.models.sharding import NO_SHARD
 from repro.serve.session import Session, SessionStore
 from repro.serve.streaming import LockstepInserter
@@ -42,20 +50,26 @@ from repro.train.serve_loop import Engine, Request
 _BIG = np.iinfo(np.int32).max
 
 
+_ROWS = ("ks", "vs")        # plan-ordered row arrays (MLA keeps only ks)
+
+
 @functools.partial(jax.jit, static_argnames=("slot", "bk"))
 def _device_trim(pstate, rows, slot: int, bk: int):
     """Zero the trimmed plan rows of one engine slot and recompute its
     centroids. ``rows`` (L, Hkv, nd) plan-order rows (sentinel S: skip)."""
-    ks, vs, ps = pstate["ks"], pstate["vs"], pstate["ps"]
-    l, _, h, s, dh = ks.shape
+    l, _, h, s, dh = pstate["ks"].shape
     li = jnp.arange(l)[:, None, None]
     hi = jnp.arange(h)[None, :, None]
-    ks = ks.at[li, slot, hi, rows].set(0.0, mode="drop")
-    vs = vs.at[li, slot, hi, rows].set(0.0, mode="drop")
-    ps = ps.at[li, slot, hi, rows].set(_BIG, mode="drop")
-    cent = pstate["cent"].at[:, slot].set(
-        ks[:, slot].astype(jnp.float32).reshape(l, h, s // bk, bk, dh).mean(3))
-    return {"ks": ks, "vs": vs, "ps": ps, "cent": cent}
+    out = dict(pstate)
+    for key in _ROWS:
+        if key in pstate:
+            out[key] = pstate[key].at[li, slot, hi, rows].set(0.0,
+                                                              mode="drop")
+    out["ps"] = pstate["ps"].at[li, slot, hi, rows].set(_BIG, mode="drop")
+    out["cent"] = pstate["cent"].at[:, slot].set(
+        out["ks"][:, slot].astype(jnp.float32)
+        .reshape(l, h, s // bk, bk, dh).mean(3))
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("slot", "bk"))
@@ -63,14 +77,25 @@ def _device_regather(pstate, gather, slot: int, bk: int):
     """Reorder one engine slot's plan-ordered rows after a host rebucket:
     ``gather`` (L, Hkv, S) maps new plan row -> old plan row."""
     l, _, h, s, dh = pstate["ks"].shape
-    ks = jnp.take_along_axis(pstate["ks"][:, slot], gather[..., None], axis=2)
-    vs = jnp.take_along_axis(pstate["vs"][:, slot], gather[..., None], axis=2)
-    ps = jnp.take_along_axis(pstate["ps"][:, slot], gather, axis=2)
-    cent = ks.astype(jnp.float32).reshape(l, h, s // bk, bk, dh).mean(3)
-    return {"ks": pstate["ks"].at[:, slot].set(ks),
-            "vs": pstate["vs"].at[:, slot].set(vs),
-            "ps": pstate["ps"].at[:, slot].set(ps),
-            "cent": pstate["cent"].at[:, slot].set(cent)}
+    out = dict(pstate)
+    for key in _ROWS:
+        if key in pstate:
+            out[key] = pstate[key].at[:, slot].set(jnp.take_along_axis(
+                pstate[key][:, slot], gather[..., None], axis=2))
+    out["ps"] = pstate["ps"].at[:, slot].set(
+        jnp.take_along_axis(pstate["ps"][:, slot], gather, axis=2))
+    cent = out["ks"][:, slot].astype(jnp.float32).reshape(
+        l, h, s // bk, bk, dh).mean(3)
+    out["cent"] = pstate["cent"].at[:, slot].set(cent)
+    return out
+
+
+@jax.jit
+def _latent_rows(cache_1):
+    """One prefilled sequence's latent rows ``[c || k_rope]`` (L, blen, R)
+    in float32, as one array for one fetch."""
+    return jnp.concatenate([cache_1["c"][:, 0], cache_1["kr"][:, 0]],
+                           -1).astype(jnp.float32)
 
 
 class ClusterKVEngine(Engine):
@@ -92,9 +117,8 @@ class ClusterKVEngine(Engine):
             cfg = dataclasses.replace(
                 cfg, clusterkv=dataclasses.replace(cfg.clusterkv,
                                                    enabled=True))
-        if mode == "plan" and cfg.mla is not None:
-            raise NotImplementedError("plan service serves GQA caches")
         self.mode = mode
+        self.mla = cfg.mla is not None
         self.knn = knn
         self.plan_prefill = plan_prefill
         self.decode_traces = 0
@@ -109,20 +133,35 @@ class ClusterKVEngine(Engine):
             raise ValueError("max_seq must be a multiple of block_k")
         self.bk = bk
         self.L = self.cfg.n_layers
-        self.Hkv = self.cfg.n_kv_heads
-        self.dh = self.cfg.head_dim
+        # one latent plan per layer for MLA, else one per layer and KV head
+        self.Hkv = 1 if self.mla else self.cfg.n_kv_heads
+        self.dh = (mla_mod.latent_dim(self.cfg) if self.mla
+                   else self.cfg.head_dim)
         if mode == "plan":
+            # plan state replaces the time-ordered cache the base engine
+            # made; only percall decode reads that one
+            self.cache = None
             dt = jnp.dtype(self.cfg.dtype)
             shape = (self.L, slots, self.Hkv)
+            # device row width: latent rows are stored zero-padded
+            self._width = (mla_mod.row_width(self.cfg) if self.mla
+                           else self.dh)
             self.pstate = {
-                "ks": jnp.zeros(shape + (max_seq, self.dh), dt),
-                "vs": jnp.zeros(shape + (max_seq, self.dh), dt),
+                "ks": jnp.zeros(shape + (max_seq, self._width), dt),
                 "ps": jnp.full(shape + (max_seq,), _BIG, jnp.int32),
-                "cent": jnp.zeros(shape + (max_seq // bk, self.dh),
+                "cent": jnp.zeros(shape + (max_seq // bk, self._width),
                                   jnp.float32),
             }
             self._pend_k = jnp.zeros(shape + (self.dh,), dt)
-            self._pend_v = jnp.zeros(shape + (self.dh,), dt)
+            if self.mla:
+                self.pstate["stats"] = jnp.zeros(
+                    (len(mla_mod.COUNTERS), 2), jnp.int32)
+                self._pend_v = None
+            else:
+                self.pstate["vs"] = jnp.zeros(shape + (max_seq, self.dh), dt)
+                self._pend_v = jnp.zeros(shape + (self.dh,), dt)
+            # the prompts' routing (MoE prefill), summed on the device
+            self._admit_moe = None
             self._pend_phys = np.full(shape, -1, np.int64)
             self._pend_pos = np.zeros(slots, np.int32)
             self._slot_sess: List[Optional[Session]] = [None] * slots
@@ -176,14 +215,24 @@ class ClusterKVEngine(Engine):
             raise ValueError(
                 f"prefill bucket {blen} must exceed knn={self.knn} (spec "
                 "unification pins every member's k to knn)")
-        # the prefill batch is one request, so these are the slot's bytes
-        with span("admit.kv_out",
-                  bytes=cache_1["k"].nbytes + cache_1["v"].nbytes):
-            # (L, Hkv, blen, dh)
-            k_np = np.asarray(cache_1["k"][:, 0], np.float32)
-            v_np = np.asarray(cache_1["v"][:, 0], np.float32)
         S = self.max_seq
-        with span("admit.plans", layers=self.L):
+        if self.mla:
+            if "moe" in cache_1:
+                self._admit_moe = (cache_1["moe"] if self._admit_moe is None
+                                   else self._admit_moe + cache_1["moe"])
+            lat = _latent_rows(cache_1)
+            with span("admit.kv_out", bytes=lat.nbytes):
+                k_np = np.asarray(lat)[:, None]          # (L, 1, blen, R)
+            v_np = None
+        else:
+            # the prefill batch is one request, so these are the slot's
+            # bytes
+            with span("admit.kv_out",
+                      bytes=cache_1["k"].nbytes + cache_1["v"].nbytes):
+                # (L, Hkv, blen, dh)
+                k_np = np.asarray(cache_1["k"][:, 0], np.float32)
+                v_np = np.asarray(cache_1["v"][:, 0], np.float32)
+        with span("admit.plans", layers=self.L, dim=self.dh):
             plans = [ckv.kv_plan_batch(jnp.asarray(k_np[l]),
                                        d=self.cfg.clusterkv.embed_dim,
                                        knn=self.knn, capacity=S)
@@ -193,22 +242,25 @@ class ClusterKVEngine(Engine):
             # rows are capacity holes (INT32_MAX position sentinel)
             pi = np.stack([np.asarray(pb.data.pi)
                            for pb in plans])                   # (L,Hkv,S)
-            k_pad = np.zeros((self.L, self.Hkv, S, self.dh), np.float32)
-            v_pad = np.zeros((self.L, self.Hkv, S, self.dh), np.float32)
-            k_pad[:, :, :blen], v_pad[:, :, :blen] = k_np, v_np
-            ks = np.take_along_axis(k_pad, pi[..., None], axis=2)
-            vs = np.take_along_axis(v_pad, pi[..., None], axis=2)
+            rows = {"ks": k_np, "vs": v_np}
+            ordered = {}
+            for key, val in rows.items():
+                if val is None:
+                    continue
+                pad = np.zeros((self.L, self.Hkv, S, self._width),
+                               np.float32)
+                pad[:, :, :blen, :self.dh] = val
+                ordered[key] = np.take_along_axis(pad, pi[..., None], axis=2)
             ps = np.where(pi < blen, pi, _BIG).astype(np.int32)
-            cent = ks.reshape(self.L, self.Hkv, S // self.bk, self.bk,
-                              self.dh).mean(3)
+            cent = ordered["ks"].reshape(self.L, self.Hkv, S // self.bk,
+                                         self.bk, self._width).mean(3)
             dt = self.pstate["ks"].dtype
-            self.pstate = {
-                "ks": self.pstate["ks"].at[:, s].set(jnp.asarray(ks, dt)),
-                "vs": self.pstate["vs"].at[:, s].set(jnp.asarray(vs, dt)),
-                "ps": self.pstate["ps"].at[:, s].set(jnp.asarray(ps)),
-                "cent": self.pstate["cent"].at[:, s].set(
-                    jnp.asarray(cent)),
-            }
+            new = dict(self.pstate)
+            for key, val in ordered.items():
+                new[key] = self.pstate[key].at[:, s].set(jnp.asarray(val, dt))
+            new["ps"] = self.pstate["ps"].at[:, s].set(jnp.asarray(ps))
+            new["cent"] = self.pstate["cent"].at[:, s].set(jnp.asarray(cent))
+            self.pstate = new
         self._pend_phys[:, s] = -1
         self._plan_gen[s] = 0
         self.inserter.attach(s, plans, generation=0)
@@ -279,9 +331,15 @@ class ClusterKVEngine(Engine):
             tokens = np.zeros((self.slots, 1), np.int32)
             for s in active:
                 tokens[s, 0] = self.slot_req[s].output[-1]
-            pend = {"k": self._pend_k, "v": self._pend_v,
+            pend = {"k": self._pend_k,
                     "slot": jnp.asarray(self._pend_slots()),
                     "pos": jnp.asarray(self._pend_pos)}
+            if self.mla:
+                live = np.zeros(self.slots, bool)
+                live[active] = True
+                pend["active"] = jnp.asarray(live)
+            else:
+                pend["v"] = self._pend_v
             with span("decode.dispatch"):
                 logits, self.pstate, nk, nv = self._plan_decode(
                     self.params, self.pstate, pend, jnp.asarray(tokens),
@@ -398,12 +456,13 @@ class ClusterKVEngine(Engine):
         # bf16 has no npz representation: widen to f32 (lossless); resume
         # casts back to the cache dtype
         f32 = jnp.float32
-        ks = np.asarray(self.pstate["ks"].astype(f32))
-        vs = np.asarray(self.pstate["vs"].astype(f32))
+        rows = {key: np.asarray(self.pstate[key].astype(f32))
+                for key in _ROWS if key in self.pstate}
         ps = np.asarray(self.pstate["ps"])
         cent = np.asarray(self.pstate["cent"])
-        pend_k = np.asarray(self._pend_k.astype(f32))
-        pend_v = np.asarray(self._pend_v.astype(f32))
+        pend = {"pend_k": np.asarray(self._pend_k.astype(f32))}
+        if self._pend_v is not None:
+            pend["pend_v"] = np.asarray(self._pend_v.astype(f32))
         for sess in self.store.sessions.values():
             s = sess.slot
             req = self.slot_req[s]
@@ -412,9 +471,9 @@ class ClusterKVEngine(Engine):
                          if hist_pos.size
                          else np.zeros((0, self.L, self.Hkv), np.int64))
             sess.aux = {
-                "ks": ks[:, s], "vs": vs[:, s], "ps": ps[:, s],
-                "cent": cent[:, s],
-                "pend_k": pend_k[:, s], "pend_v": pend_v[:, s],
+                **{key: val[:, s] for key, val in rows.items()},
+                **{key: val[:, s] for key, val in pend.items()},
+                "ps": ps[:, s], "cent": cent[:, s],
                 "pend_phys": self._pend_phys[:, s].copy(),
                 "pend_pos": np.asarray(self._pend_pos[s], np.int32),
                 "slot_pos": np.asarray(self.slot_pos[s], np.int32),
@@ -439,19 +498,20 @@ class ClusterKVEngine(Engine):
             s, aux = sess.slot, sess.aux
             sess.phys_hist = {int(p): aux["hist_phys"][i]
                               for i, p in enumerate(aux["hist_pos"])}
-            self.pstate = {
-                "ks": self.pstate["ks"].at[:, s].set(
-                    jnp.asarray(aux["ks"], dt)),
-                "vs": self.pstate["vs"].at[:, s].set(
-                    jnp.asarray(aux["vs"], dt)),
-                "ps": self.pstate["ps"].at[:, s].set(jnp.asarray(aux["ps"])),
-                "cent": self.pstate["cent"].at[:, s].set(
-                    jnp.asarray(aux["cent"])),
-            }
+            new = dict(self.pstate)
+            for key in _ROWS:
+                if key in self.pstate:
+                    new[key] = self.pstate[key].at[:, s].set(
+                        jnp.asarray(aux[key], dt))
+            new["ps"] = self.pstate["ps"].at[:, s].set(jnp.asarray(aux["ps"]))
+            new["cent"] = self.pstate["cent"].at[:, s].set(
+                jnp.asarray(aux["cent"]))
+            self.pstate = new
             self._pend_k = self._pend_k.at[:, s].set(
                 jnp.asarray(aux["pend_k"], dt))
-            self._pend_v = self._pend_v.at[:, s].set(
-                jnp.asarray(aux["pend_v"], dt))
+            if self._pend_v is not None:
+                self._pend_v = self._pend_v.at[:, s].set(
+                    jnp.asarray(aux["pend_v"], dt))
             self._pend_phys[:, s] = aux["pend_phys"]
             self._pend_pos[s] = int(aux["pend_pos"])
             self.slot_pos[s] = int(aux["slot_pos"])
@@ -466,6 +526,19 @@ class ClusterKVEngine(Engine):
             self.inserter.attach(s, sess.plans, generation=0)
 
     # -- telemetry ----------------------------------------------------------
+
+    def counters(self) -> Dict[str, int]:
+        """The MLA tick's device-side counters (``models.mla.COUNTERS``),
+        plus the prompts' MoE routing as ``prefill_<name>``, in one fetch
+        each; empty for a GQA service, whose tick keeps none."""
+        if self.mode != "plan" or not self.mla:
+            return {}
+        out = mla_mod.read_counters(self.pstate["stats"])
+        if self._admit_moe is not None:
+            from repro.models.moe import MOE_STATS
+            out.update({f"prefill_{n}": int(v) for n, v in
+                        zip(MOE_STATS, np.asarray(self._admit_moe))})
+        return out
 
     def report(self) -> dict:
         """Machine-readable service telemetry (JSON-safe)."""

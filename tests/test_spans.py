@@ -125,7 +125,7 @@ def test_admission_plans_hold_one_build_per_layer_and_head(serve):
     plans = named(spans, "admit.plans")
     assert len(plans) == len(PROMPTS)
     for p in plans:
-        assert p.stats == {"layers": eng.L}
+        assert p.stats == {"layers": eng.L, "dim": eng.dh}
         builds = [s for s in named(spans, "build") if p.holds(s)]
         assert len(builds) == eng.L * eng.Hkv
         # one stacking of each layer's member plans into its batch

@@ -148,3 +148,45 @@ def test_tsne_force_compiles(one_chip, bs, d):
         _spec((n_rb, nbr), jnp.int32, one_chip),
         _spec((n, d), jnp.float32, one_chip)).compile()
     assert _has_kernel(compiled)
+
+
+def test_mla_plan_decode_step_compiles(one_chip):
+    """The decode service's tick for DeepSeek-V2-Lite at the
+    ``deepseek-v2-lite.chat-2k`` cell's shapes: 27 layers, 16 slots,
+    max_seq 2,048, latent rows of 576 (stored 640 wide) whose first 512
+    columns are the values, the 8 held experts of 64. Each layer's rows
+    are gathered from the latent stack in place: the temporaries stay
+    under half the 1.13 GB latent cache (copying each layer's slice out
+    first makes them 1.12 GB), and the whole fits the chip beside the
+    inserter's float32 mirror of the latent rows (2 GB)."""
+    from repro.configs.deepseek_v2_lite import CONFIG
+    from repro.models import mla, model_api, transformer
+    from repro.models.sharding import NO_SHARD
+
+    nl, b, s, bk = CONFIG.n_layers, 16, 2048, 128
+    r, w = mla.latent_dim(CONFIG), mla.row_width(CONFIG)
+    assert (r, w) == (576, 640)
+    shapes = jax.eval_shape(lambda k: model_api.init(CONFIG, k)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          shapes)
+    bf = jnp.bfloat16
+    pstate = {"ks": _spec((nl, b, 1, s, w), bf, one_chip),
+              "ps": _spec((nl, b, 1, s), jnp.int32, one_chip),
+              "cent": _spec((nl, b, 1, s // bk, w), jnp.float32, one_chip),
+              "stats": _spec((len(mla.COUNTERS), 2), jnp.int32, one_chip)}
+    pend = {"k": _spec((nl, b, 1, r), bf, one_chip),
+            "slot": _spec((nl, b, 1), jnp.int32, one_chip),
+            "pos": _spec((b,), jnp.int32, one_chip),
+            "active": _spec((b,), jnp.bool_, one_chip)}
+
+    def _plan_decode_step(params, pstate, pend, tokens, slot_pos):
+        return transformer.plan_decode_step(params, CONFIG, pstate, pend,
+                                            tokens, slot_pos, NO_SHARD)
+
+    compiled = jax.jit(_plan_decode_step, donate_argnums=(1,)).lower(
+        params, pstate, pend, _spec((b, 1), jnp.int32, one_chip),
+        _spec((b,), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < nl * b * s * w * 2 // 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
